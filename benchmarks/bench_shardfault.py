@@ -17,9 +17,10 @@ soak exists to pin:
 Separate scenario rows pin the rest of the acceptance surface: with
 replication factor 2 a double-kill degrades exactly one range and
 coverage returns to 1.0 after re-replication; the hierarchical host merge
-is bit-identical across fanouts (tree == flat); and an SPMD subprocess
-(4 fake devices) pins hist_tree == hist_merge == single-device reference
-through the jitted ``engine.search_sharded`` path.
+is bit-identical across fanouts (tree == flat); and an in-process SPMD row
+over four devices (four host devices on the CPU) pins hist_tree ==
+hist_merge == single-device reference through the jitted
+``engine.search_sharded`` path.
 
 Standalone CLI (what CI's shardfault-soak-smoke job runs):
     PYTHONPATH=src python benchmarks/bench_shardfault.py \
@@ -30,7 +31,6 @@ benchmarks/run.py (tag ``shardfault``) with a short, SPMD-free preset.
 import argparse
 import json
 import os
-import subprocess
 import sys
 import time
 
@@ -178,56 +178,44 @@ def merge_identity(seed: int = 0, k: int = 16) -> dict:
     return row
 
 
-_SPMD_SCRIPT = """
-import numpy as np, jax, jax.numpy as jnp
-from jax.sharding import Mesh
-from repro.core import binary, engine
-from repro.kernels import ops
-rng = np.random.default_rng(11)
-d, N, Q, k = 64, 2048, 8, 16
-xp = binary.pack_bits(jnp.asarray(rng.integers(0, 2, (N, d)), jnp.uint8))
-qp = binary.pack_bits(jnp.asarray(rng.integers(0, 2, (Q, d)), jnp.uint8))
-mesh = Mesh(np.array(jax.devices()).reshape(4), ("data",))
-rd, ri = ops.hamming_topk(qp, xp, k, d + 1)
-with mesh:
-    hd, hi = engine.search_sharded(xp, qp, k, d, mesh, ("data",))
-    td, ti = engine.search_sharded(xp, qp, k, d, mesh, ("data",),
-                                   merge="hist_tree", fanout=2)
-assert (hd == rd).all() and (hi == ri).all(), "hist_merge != reference"
-assert (td == hd).all() and (ti == hi).all(), "hist_tree != hist_merge"
-import warnings
-part = jnp.asarray(np.array([1, 0, 1, 1], np.int32))
-surv = jnp.asarray(np.concatenate([np.asarray(xp)[:512],
-                                   np.asarray(xp)[1024:]]))
-rd2, ri2 = ops.hamming_topk(qp, surv, k, d + 1)
-with mesh, warnings.catch_warnings():
-    warnings.simplefilter("ignore")
-    md, mi = engine.search_sharded(xp, qp, k, d, mesh, ("data",),
-                                   merge="hist_tree", fanout=2,
-                                   shard_participate=part)
-assert (md == rd2).all() and (mi == ri2).all(), "masked tree != rebuild"
-print("SPMD_OK")
-"""
-
-
-def spmd_identity() -> dict:
+def spmd_identity(devices) -> dict:
     """hist_tree == hist_merge == single-device reference through the
-    jitted SPMD path, in a 4-fake-device subprocess."""
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
-                        + " --xla_force_host_platform_device_count=4")
-    env["JAX_PLATFORMS"] = "cpu"
-    src = os.path.join(os.path.dirname(__file__), "..", "src")
-    env["PYTHONPATH"] = os.pathsep.join(
-        [os.path.abspath(src), env.get("PYTHONPATH", "")])
-    proc = subprocess.run([sys.executable, "-c", _SPMD_SCRIPT], env=env,
-                          capture_output=True, text=True, timeout=900)
-    ok = proc.returncode == 0 and "SPMD_OK" in proc.stdout
-    row = {"ok": ok}
-    if not ok:
-        row["stdout"] = proc.stdout[-2000:]
-        row["stderr"] = proc.stderr[-2000:]
-    return row
+    jitted SPMD ``engine.search_sharded`` path, in this process, over four
+    of its devices."""
+    import warnings
+
+    import jax.numpy as jnp
+    from jax.sharding import Mesh
+
+    from repro.core import binary, engine
+    from repro.kernels import ops
+
+    rng = np.random.default_rng(11)
+    d, N, Q, k = 64, 2048, 8, 16
+    xp = binary.pack_bits(jnp.asarray(rng.integers(0, 2, (N, d)), jnp.uint8))
+    qp = binary.pack_bits(jnp.asarray(rng.integers(0, 2, (Q, d)), jnp.uint8))
+    mesh = Mesh(np.array(devices[:4]), ("data",))
+    rd, ri = ops.hamming_topk(qp, xp, k, d + 1)
+    with mesh:
+        hd, hi = engine.search_sharded(xp, qp, k, d, mesh, ("data",))
+        td, ti = engine.search_sharded(xp, qp, k, d, mesh, ("data",),
+                                       merge="hist_tree", fanout=2)
+    part = jnp.asarray(np.array([1, 0, 1, 1], np.int32))
+    surv = jnp.asarray(np.concatenate([np.asarray(xp)[:512],
+                                       np.asarray(xp)[1024:]]))
+    rd2, ri2 = ops.hamming_topk(qp, surv, k, d + 1)
+    with mesh, warnings.catch_warnings():
+        warnings.simplefilter("ignore")
+        md, mi = engine.search_sharded(xp, qp, k, d, mesh, ("data",),
+                                       merge="hist_tree", fanout=2,
+                                       shard_participate=part)
+    checks = {
+        "hist_merge_eq_reference": bool((hd == rd).all() and (hi == ri).all()),
+        "hist_tree_eq_hist_merge": bool((td == hd).all() and (ti == hi).all()),
+        "masked_tree_eq_rebuild": bool((md == rd2).all()
+                                       and (mi == ri2).all()),
+    }
+    return {"ok": all(checks.values()), **checks}
 
 
 def _report_rows(rows: dict, report) -> None:
@@ -248,7 +236,7 @@ def _report_rows(rows: dict, report) -> None:
 
 def run(report):
     """benchmarks/run.py hook — short preset, host-level only (the SPMD
-    subprocess row is CI's standalone invocation)."""
+    row is CI's standalone invocation)."""
     rows = {
         "soak_r1": kill_soak(ticks=40, kill_p=0.05, revive_p=0.15, factor=1),
         "soak_r2": kill_soak(ticks=40, kill_p=0.05, revive_p=0.15, factor=2),
@@ -267,7 +255,7 @@ def main():
     ap.add_argument("--revive-p", type=float, default=0.15)
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--no-spmd", action="store_true",
-                    help="skip the 4-fake-device subprocess identity row")
+                    help="skip the 4-device SPMD identity row")
     ap.add_argument("--json", default=None, metavar="PATH",
                     help="write BENCH_shardfault.json-style output to PATH")
     args = ap.parse_args()
@@ -283,7 +271,14 @@ def main():
         "merge_identity": merge_identity(seed=args.seed),
     }
     if not args.no_spmd:
-        rows["spmd_identity"] = spmd_identity()
+        import jax
+
+        devices = jax.devices()
+        if len(devices) >= 4:
+            rows["spmd_identity"] = spmd_identity(devices)
+        else:
+            print(f"spmd_identity row skipped: it needs 4 devices, this "
+                  f"process has {len(devices)}", file=sys.stderr)
 
     print("name,us_per_call,derived")
     _report_rows(rows, lambda line: print(line, flush=True))
@@ -303,4 +298,8 @@ def main():
 
 if __name__ == "__main__":
     sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
+    # four host devices for the SPMD row when the backend is the CPU; read
+    # once when jax initializes, ignored by an accelerator backend
+    os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
+                               + " --xla_force_host_platform_device_count=4")
     main()

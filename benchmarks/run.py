@@ -17,6 +17,7 @@ from benchmarks import (bench_approx, bench_compounding, bench_energy_proxy,
                         bench_serve, bench_shardfault,
                         bench_statistical_reduction, bench_tenant,
                         bench_throughput, bench_workloads)
+from repro.launch import cache
 
 BENCHES = [
     ("fig4", bench_throughput),
@@ -65,11 +66,16 @@ def main() -> None:
         if args.json:
             rows.append(_parse_row(line))
 
+    selected = [(tag, mod) for tag, mod in BENCHES
+                if not args.only or args.only in tag]
+    if not selected:
+        ap.error(f"--only {args.only!r} matches no benchmark; tags: "
+                 f"{', '.join(tag for tag, _ in BENCHES)}")
+    cache.enable_compile_cache()
+
     print("name,us_per_call,derived")
     failed = []
-    for tag, mod in BENCHES:
-        if args.only and args.only not in tag:
-            continue
+    for tag, mod in selected:
         try:
             mod.run(report)
         except Exception:  # noqa: BLE001

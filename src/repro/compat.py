@@ -1,29 +1,17 @@
-"""Small JAX version-compat shims (jax>=0.8 renamed a few knobs)."""
+"""The repo's spellings of ``shard_map`` and ``make_mesh``: replication
+checks off, and every mesh axis ``Auto`` (GSPMD propagates through them)."""
 from __future__ import annotations
 
-import inspect
-
 import jax
-
-try:
-    from jax import shard_map as _shard_map
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-_CHECK_KW = ("check_vma" if "check_vma" in inspect.signature(_shard_map).parameters
-             else "check_rep")
+from jax import shard_map as _shard_map
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, check=False):
     return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      **{_CHECK_KW: check})
+                      check_vma=check)
 
 
 def make_mesh(shape, axis_names):
-    try:
-        return jax.make_mesh(
-            shape, axis_names,
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names))
-    except (TypeError, AttributeError):  # pragma: no cover - older jax
-        # older jax: make_mesh lacks axis_types / jax.sharding.AxisType absent
-        return jax.make_mesh(shape, axis_names)
+    return jax.make_mesh(
+        shape, axis_names,
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names))

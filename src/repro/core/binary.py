@@ -45,6 +45,33 @@ def unpack_bits(packed: jax.Array, d: int) -> jax.Array:
     return bits.reshape(*packed.shape[:-1], packed.shape[-1] * WORD)[..., :d].astype(jnp.uint8)
 
 
+def _bit(packed: jax.Array, shift) -> jax.Array:
+    """Bit ``shift`` of every 32-bit word, as int32 0/1."""
+    u = packed if packed.dtype == jnp.uint32 else packed.astype(jnp.uint32)
+    return (jax.lax.shift_right_logical(u, jnp.asarray(shift, jnp.uint32))
+            & jnp.uint32(1)).astype(jnp.int32)
+
+
+def bit_means(packed: jax.Array, d: int) -> jax.Array:
+    """packed: (N, W) -> (d,) float32 fraction of rows with each bit set,
+    counted word-parallel per bit offset (32 reductions over the packed
+    array; nothing of size (N, d) is built)."""
+    counts = jnp.stack([jnp.sum(_bit(packed, s), axis=0)
+                        for s in range(WORD)], axis=1)          # (W, 32)
+    return counts.reshape(-1)[:d].astype(jnp.float32) / packed.shape[0]
+
+
+def bit_key(packed: jax.Array, positions: jax.Array) -> jax.Array:
+    """packed: (N, W), positions: (b,) bit indices -> (N,) int32 key
+    sum_i bit(positions[i]) << i, reading one word column per bit."""
+    key = jnp.zeros(packed.shape[:1], jnp.int32)
+    for i in range(positions.shape[0]):
+        p = positions[i]
+        word = jnp.take(packed, p // WORD, axis=1)
+        key = key + (_bit(word, p % WORD) << i)
+    return key
+
+
 def hamming_ref(q_bits: jax.Array, x_bits: jax.Array) -> jax.Array:
     """Oracle: q_bits (Q, d), x_bits (N, d) in {0,1} -> (Q, N) int32."""
     diff = q_bits[:, None, :].astype(jnp.int32) != x_bits[None, :, :].astype(jnp.int32)

@@ -106,15 +106,16 @@ def hamming_prefix_assign(codes: jax.Array, d: int, bits: int,
     Pass ``positions`` to reuse a previous selection (e.g. to key queries
     the same way the datastore was keyed).
 
-    Returns (assign (N,) int32 in [0, 2^bits), positions (bits,) int32)."""
-    b = binary.unpack_bits(codes, d)                       # (N, d)
+    Returns (assign (N,) int32 in [0, 2^bits), positions (bits,) int32).
+
+    Both the bit means and the key are read straight from the packed words
+    (``binary.bit_means`` / ``binary.bit_key``): nothing of size (N, d)
+    is built, so a datastore that fills the device can be keyed."""
     if positions is None:
-        means = jnp.mean(b.astype(jnp.float32), axis=0)
+        means = binary.bit_means(codes, d)
         positions = jnp.argsort(jnp.abs(means - 0.5),
                                 stable=True)[:bits].astype(jnp.int32)
-    sel = b[:, positions].astype(jnp.int32)                # (N, bits)
-    weights = (1 << jnp.arange(positions.shape[0], dtype=jnp.int32))
-    return jnp.sum(sel * weights, axis=-1), positions
+    return binary.bit_key(codes, positions), positions
 
 
 def default_bits(n: int) -> int:
@@ -160,8 +161,7 @@ def local_sort(codes: jax.Array, d: int, bits: int | None = None,
     bits = bits if bits is not None else default_bits(n)
     bits = max(1, min(bits, d))
     positions = jnp.arange(bits, dtype=jnp.int32) * (d // bits)
-    b = binary.unpack_bits(codes, d)[:, positions].astype(jnp.int32)
-    key = jnp.sum(b * (1 << jnp.arange(bits, dtype=jnp.int32)), axis=-1)
+    key = binary.bit_key(codes, positions)
     if n_valid is not None:
         key = jnp.where(jnp.arange(n) < jnp.asarray(n_valid, jnp.int32),
                         key, jnp.int32(1) << 30)

@@ -66,7 +66,7 @@ def synthetic_datastore(cfg: ModelConfig, n: Optional[int] = None, key=None) -> 
     key = key if key is not None else jax.random.PRNGKey(3)
     k1, k2 = jax.random.split(key)
     W = binary.padded_words(r.code_bits)
-    codes = jax.random.randint(k1, (n, W), 0, 2**31 - 1, jnp.int32).astype(jnp.uint32)
+    codes = jax.random.bits(k1, (n, W), jnp.uint32)       # all 32 bits
     values = jax.random.randint(k2, (n,), 0, cfg.vocab_size, jnp.int32)
     itq = quantize.ITQParams(
         mean=jnp.zeros((cfg.d_model,), jnp.float32),

@@ -1,8 +1,8 @@
 """Jit'd public wrappers for the Pallas kernels.
 
-On CPU (this container) kernels execute with ``interpret=True`` — the kernel
+On the CPU backend kernels execute with ``interpret=True`` — the kernel
 body runs faithfully in Python/XLA for correctness validation; on TPU the
-same calls compile to Mosaic. Shapes are padded to block multiples here so
+same calls compile to Mosaic (and nothing interprets). Shapes are padded to block multiples here so
 the kernels stay assert-simple; padded dataset rows are masked exactly
 inside the kernels by the ``n_valid`` scalar. Block shapes come from the
 shared heuristic in kernels/tuning.py unless explicitly overridden.
@@ -82,8 +82,10 @@ def _topk_blocked(q_packed: jax.Array, x_packed: jax.Array, lanes: int,
     Q, W = q_packed.shape
     N = x_packed.shape[0]
     bq, bn, sub, q_pad, n_pad = topk_geometry(Q, N, W, lanes, bq, bn, sub)
-    qp = _pad_rows(q_packed.astype(jnp.int32), q_pad)
-    xp = _pad_rows(x_packed.astype(jnp.int32), n_pad)
+    # 32-bit codes stay as stored: converting a uint32 datastore would copy
+    # it (the kernels read either)
+    qp = _pad_rows(q_packed, q_pad)
+    xp = _pad_rows(x_packed, n_pad)
     return qp, xp, bq, bn, sub
 
 

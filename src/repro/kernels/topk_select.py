@@ -5,8 +5,8 @@ counters race toward a threshold and nearer vectors *report earlier*, so the
 sort is a counting process over the bounded domain [0, d]. These two kernels
 are that pipeline on TPU — the (Q, N) distance matrix never exists in HBM:
 
-* **pass 1** (``hamming_hist_pallas``, the "race"): stream (BN, W) code
-  tiles HBM->VMEM, XOR+popcount against the query tile, and accumulate a
+* **pass 1** (``hamming_hist_pallas``, the "race"): stream code tiles
+  HBM->VMEM, XOR+popcount against the query tile, and accumulate a
   per-query distance histogram. Only (Q, bins) counts leave the kernel —
   the same reduction the AP performs by keeping counters next to the
   Hamming macros.
@@ -25,12 +25,33 @@ Both kernels take the valid-row count ``n_valid`` as a scalar (SMEM) so
 padded dataset rows — block-alignment padding here, chunk padding in the
 engine's scan — are masked exactly, by global row id, inside the kernel.
 
-Grid is (Q/BQ, N/BN) with the N dimension innermost; output tiles map to
+**Tile layout.** Every vector value is built from whole (8, 128) tiles,
+the shape Mosaic (the TPU kernel compiler) lays out natively:
+
+* the codes enter TRANSPOSED, (W, N): data rows run along the 128 lanes.
+  A (N, W) int32 array with W <= 8 would pad every row to 128 lanes in
+  VMEM (and force a 16x padded relayout of the whole datastore in HBM);
+  (W, N) is the physical layout XLA already gives a narrow (N, W) array,
+  so the transpose in the wrappers is free;
+* a sub-tile's distances are computed as a (BQ, sub) tile (queries on
+  sublanes, rows on lanes) by a static loop over the W words, then
+  transposed once to (sub, BQ) so that rows run down the sublanes;
+* the scatters (pass-1 histogram, pass-2 slot one-hot) then consume that
+  transposed tile 8 rows (one sublane group) at a time: an (8, BQ) slice
+  compared against a (lanes, 8, BQ) iota and accumulated elementwise into a
+  (lanes, 8, BQ) output block (one per query block, so any BQ that is a
+  multiple of 8 is a legal block). No reduction or relayout runs in the
+  inner loop; the wrappers sum the 8 sublane partials and transpose back;
+* pass 2's in-tile ranks (the prefix count of winners in index order) are
+  one 0/1 matmul with a (sub, sub) lower-triangular matrix — exact in
+  bf16 x bf16 -> f32 for sub <= 2^24;
+* per-query vectors (r*, n_lt, slot_base) enter as one (1, BQ) row per
+  query block.
+
+Grid is (Q/BQ, N/BN) with the N dimension innermost; output blocks map to
 the same block for every j and are revisited: initialized at j == 0,
 accumulated thereafter. Running per-query emit counts for pass 2 are carried
-across j in a VMEM scratch. The (BQ, sub, lanes) one-hot intermediates are
-kept small by an inner fori over BN/sub sub-tiles (block shapes from
-kernels/tuning.py).
+across j in a VMEM scratch.
 
 The grid owns the WHOLE datastore in one invocation (kernels/ops.py pads N
 to a block multiple; the engine no longer chunk-scans this path), which
@@ -38,23 +59,23 @@ enables **block-min pruning**: pass 1 additionally emits a tiny
 (Q/BQ, N/BN) int32 summary — the minimum valid distance in each
 (query-block, data-block) tile. Pass 2 compares each tile's summary entry
 against the widest winning radius max(r*) of its query block and wraps the
-entire recompute+emit body in ``pl.when(block_min <= max(r*))``: a tile
-that provably holds no winner costs one SMEM scalar compare instead of a
-re-streamed XOR/popcount/scatter. On clustered or sorted datastores most
-pass-2 tiles skip. Skipping is exact — the emit counters only ever advance
-on winners, so an all-loser tile leaves every carried count and output slot
-untouched.
+entire recompute+emit body in ``pl.when``: a tile that provably holds no
+winner costs one SMEM scalar compare instead of a re-streamed
+XOR/popcount/scatter. On clustered or sorted datastores most pass-2 tiles
+skip. Skipping is exact — the emit counters only ever advance on winners,
+so an all-loser tile leaves every carried count and output slot untouched.
 
 Both kernels additionally take a per-(query-block, data-block) **enable
-mask** of the same (Q/BQ, N/BN) shape (one SMEM scalar per tile, all-ones
-when the caller passes none). A disabled tile is *outside the candidate
-set* — the index-probing contract of core/layout.py: pass 1 skips it
-outright (it contributes nothing to any histogram and summarizes to
-``bins``, so every query's r* is computed over the enabled rows only),
-and pass 2 composes the mask with the block-min bound. Because r* derives
-from the masked histogram, skipping disabled tiles in pass 2 is exact in
-the same sense as the block-min skip: no enabled (q, x) pair is ever
-dropped, disabled pairs were never candidates.
+mask** of the same (Q/BQ, N/BN) shape (all-ones when the caller passes
+none). A disabled tile is *outside the candidate set* — the index-probing
+contract of core/layout.py: pass 1 skips it outright (it contributes
+nothing to any histogram and summarizes to ``bins``, so every query's r* is
+computed over the enabled rows only), and pass 2 composes the mask with
+the block-min bound. Because r* derives from the masked histogram, skipping
+disabled tiles in pass 2 is exact in the same sense as the block-min skip:
+no enabled (q, x) pair is ever dropped, disabled pairs were never
+candidates. The per-tile scalars (mask, summary, pass-2 run flag) live in
+SMEM as one (1, 1, N/BN) row per query block, indexed by ``program_id``.
 
 The emit pass finally takes two **sharding hooks** — the paper's counters
 are additive partial histograms, so the same two kernels serve the
@@ -76,19 +97,65 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+# rows of the transposed distance tile one scatter step consumes: one
+# sublane group, so every scatter operand is a whole number of vregs
+_GROUP = 8
+
+
+def _as_i32(a: jax.Array) -> jax.Array:
+    return a if a.dtype == jnp.int32 else a.astype(jnp.int32)
+
+
+def _codes(q_packed: jax.Array, x_packed: jax.Array):
+    """Packed codes as the kernels take them: 32-bit words as stored
+    (converting a uint32 datastore would copy it), queries in the codes'
+    dtype, codes transposed to (W, N) — a free bitcast of XLA's layout for
+    a narrow (N, W) array."""
+    x = x_packed if x_packed.dtype in (jnp.int32, jnp.uint32) else (
+        x_packed.astype(jnp.int32))
+    return q_packed.astype(x.dtype), x.T
+
 
 def _tile_dist(q, xs, bins: int):
-    """(BQ, W) x (sub, W) int32 packed -> (BQ, sub) clamped distances."""
-    xor = jax.lax.bitwise_xor(q[:, None, :], xs[None, :, :])
-    dist = jnp.sum(jax.lax.population_count(xor).astype(jnp.int32), axis=-1)
+    """(BQ, W) queries x (W, sub) transposed codes -> (BQ, sub) clamped
+    distances, rows on lanes: one 2-D XOR+popcount per code word."""
+    dist = None
+    for w in range(q.shape[1]):
+        pc = jax.lax.population_count(
+            jax.lax.bitwise_xor(q[:, w:w + 1], xs[w:w + 1, :])
+        ).astype(jnp.int32)
+        dist = pc if dist is None else dist + pc
     return jnp.minimum(dist, bins - 1)
+
+
+def _sub_tiles(x_ref, bn: int, sub: int, body, init):
+    """Run ``body(start, xs, carry)`` over the (W, sub) code sub-tiles of
+    the (W, bn) block. A single sub-tile is sliced statically (no dynamic
+    lane offset for Mosaic to align)."""
+    if bn == sub:
+        return body(0, x_ref[...], init)
+
+    def step(s, carry):
+        start = pl.multiple_of(s * sub, sub)
+        return body(start, x_ref[:, pl.ds(start, sub)], carry)
+
+    return jax.lax.fori_loop(0, bn // sub, step, init)
+
+
+def _scatter_groups(sub: int, fn):
+    """Apply ``fn(offset)`` to each 8-row group of a (sub, BQ) tile."""
+    def step(g, carry):
+        fn(pl.multiple_of(g * _GROUP, _GROUP))
+        return carry
+
+    jax.lax.fori_loop(0, sub // _GROUP, step, 0)
 
 
 # ---------------------------------------------------------------------------
 # pass 1: fused distance + histogram (the "race")
 # ---------------------------------------------------------------------------
 
-def _hist_kernel(nv_ref, en_ref, q_ref, x_ref, hist_ref, bmin_ref, *,
+def _hist_kernel(nv_ref, en_ref, q_ref, x_ref, hist_ref, bmin_ref, dt_ref, *,
                  bins: int, sub: int, bn: int):
     j = pl.program_id(1)
 
@@ -98,36 +165,51 @@ def _hist_kernel(nv_ref, en_ref, q_ref, x_ref, hist_ref, bmin_ref, *,
 
     # a disabled tile is outside the candidate set: it contributes nothing
     # to the histogram and summarizes to bins, so pass 2 skips it too
-    bmin_ref[0, 0] = jnp.int32(bins)
+    bmin_ref[0, 0, j] = jnp.int32(bins)
 
-    @pl.when(en_ref[0, 0] != 0)
+    @pl.when(en_ref[0, 0, j] != 0)
     def _work():
         n_valid = nv_ref[0]
-        q = q_ref[...]                              # (BQ, W)
-        x = x_ref[...]                              # (BN, W)
+        q = q_ref[...]                                     # (BQ, W)
         bq = q.shape[0]
-        bin_iota = jax.lax.broadcasted_iota(jnp.int32, (1, 1, bins), 2)
-        base = j * bn
+        bin_ids = jax.lax.broadcasted_iota(jnp.int32, (bins, _GROUP, bq), 0)
 
-        def body(s, carry):
-            acc, bmin = carry
-            xs = jax.lax.dynamic_slice_in_dim(x, s * sub, sub, axis=0)
-            dist = _tile_dist(q, xs, bins)
-            gid = base + s * sub + jax.lax.broadcasted_iota(
+        def count(off):
+            d8 = dt_ref[pl.ds(off, _GROUP), :]             # (8, BQ)
+            hist_ref[0] += (d8[None] == bin_ids).astype(jnp.int32)
+
+        def body(start, xs, bmin):
+            dist = _tile_dist(q, xs, bins)                 # (BQ, sub)
+            gid = j * bn + start + jax.lax.broadcasted_iota(
                 jnp.int32, (1, sub), 1)
-            valid = gid < n_valid                                  # (1, sub)
-            onehot = (dist[:, :, None] == bin_iota) & valid[:, :, None]
-            acc = acc + jnp.sum(onehot.astype(jnp.int32), axis=1)
-            # invalid (padding) rows report bins: a fully-padded tile
-            # summarizes to bins > any possible r*, so pass 2 always skips it
-            bmin = jnp.minimum(bmin, jnp.min(jnp.where(valid, dist, bins)))
-            return acc, bmin
+            # invalid (padding) rows become bins: outside every histogram
+            # bin, and a fully-padded tile summarizes to bins > any r*
+            dist = jnp.where(gid < n_valid, dist, bins)
+            dt_ref[...] = dist.T                           # (sub, BQ)
+            _scatter_groups(sub, count)
+            return jnp.minimum(bmin, dist)
 
-        acc, bmin = jax.lax.fori_loop(
-            0, bn // sub, body,
-            (jnp.zeros((bq, bins), jnp.int32), jnp.int32(bins)))
-        hist_ref[...] += acc
-        bmin_ref[0, 0] = bmin
+        bmin = _sub_tiles(x_ref, bn, sub, body,
+                          jnp.full((bq, sub), bins, jnp.int32))
+        bmin_ref[0, 0, j] = jnp.min(bmin)
+
+
+def _tile_rows(a: jax.Array, nq: int, nj: int) -> jax.Array:
+    """(nq, nj) per-tile scalars -> the kernels' (nq, 1, nj) SMEM rows."""
+    a = _as_i32(a)
+    assert a.shape == (nq, nj), (a.shape, nq, nj)
+    return a.reshape(nq, 1, nj)
+
+
+def _unblock(a: jax.Array) -> jax.Array:
+    """(Q/bq, lanes, 8, bq) per-group partials -> (Q, lanes)."""
+    nq, lanes, _, bq = a.shape
+    return jnp.sum(a, axis=2).transpose(0, 2, 1).reshape(nq * bq, lanes)
+
+
+def _row_spec(nj: int):
+    return pl.BlockSpec((1, 1, nj), lambda i, j: (i, 0, 0),
+                        memory_space=pltpu.SMEM)
 
 
 @functools.partial(jax.jit, static_argnames=("bins", "bq", "bn", "sub",
@@ -152,46 +234,44 @@ def hamming_hist_pallas(q_packed: jax.Array, x_packed: jax.Array, bins: int,
     N, _ = x_packed.shape
     bq, bn = min(bq, Q), min(bn, N)
     sub = min(sub, bn)
-    assert Q % bq == 0 and N % bn == 0 and bn % sub == 0, (Q, N, bq, bn, sub)
-    q32 = q_packed.astype(jnp.int32) if q_packed.dtype != jnp.int32 else q_packed
-    x32 = x_packed.astype(jnp.int32) if x_packed.dtype != jnp.int32 else x_packed
+    assert (Q % bq == 0 and N % bn == 0 and bn % sub == 0
+            and sub % _GROUP == 0), (Q, N, bq, bn, sub)
+    nq, nj = Q // bq, N // bn
     nv = jnp.full((1,), N, jnp.int32) if n_valid is None else (
         jnp.asarray(n_valid, jnp.int32).reshape(1))
-    en = (jnp.ones((Q // bq, N // bn), jnp.int32) if block_mask is None
-          else block_mask.astype(jnp.int32))
-    assert en.shape == (Q // bq, N // bn), (en.shape, Q // bq, N // bn)
+    en = _tile_rows(jnp.ones((nq, nj), jnp.int32) if block_mask is None
+                    else block_mask, nq, nj)
 
-    grid = (Q // bq, N // bn)
-    return pl.pallas_call(
+    hist8, bmin = pl.pallas_call(
         functools.partial(_hist_kernel, bins=bins, sub=sub, bn=bn),
-        grid=grid,
+        grid=(nq, nj),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, 1), lambda i, j: (i, j),
-                         memory_space=pltpu.SMEM),
+            _row_spec(nj),
             pl.BlockSpec((bq, W), lambda i, j: (i, 0)),
-            pl.BlockSpec((bn, W), lambda i, j: (j, 0)),
+            pl.BlockSpec((W, bn), lambda i, j: (0, j)),
         ],
         out_specs=[
-            pl.BlockSpec((bq, bins), lambda i, j: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i, j: (i, j),
-                         memory_space=pltpu.SMEM),
+            pl.BlockSpec((1, bins, _GROUP, bq), lambda i, j: (i, 0, 0, 0)),
+            _row_spec(nj),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((Q, bins), jnp.int32),
-            jax.ShapeDtypeStruct((Q // bq, N // bn), jnp.int32),
+            jax.ShapeDtypeStruct((nq, bins, _GROUP, bq), jnp.int32),
+            jax.ShapeDtypeStruct((nq, 1, nj), jnp.int32),
         ],
+        scratch_shapes=[pltpu.VMEM((sub, bq), jnp.int32)],
         interpret=interpret,
-    )(nv, en, q32, x32)
+    )(nv, en, *_codes(q_packed, x_packed))
+    return _unblock(hist8), bmin.reshape(nq, nj)
 
 
 # ---------------------------------------------------------------------------
 # pass 2: re-stream + emit winners (the "reports")
 # ---------------------------------------------------------------------------
 
-def _emit_kernel(nv_ref, ib_ref, en_ref, bm_ref, q_ref, x_ref, r_ref,
-                 nlt_ref, sb_ref, outd_ref, outi_ref, cnt_ref, *, bins: int,
-                 k: int, sub: int, bn: int):
+def _emit_kernel(nv_ref, ib_ref, run_ref, q_ref, x_ref, r_ref, nlt_ref,
+                 sb_ref, outd_ref, outi_ref, cnt_ref, dt_ref, st_ref, *,
+                 bins: int, k: int, sub: int, bn: int):
     j = pl.program_id(1)
 
     @pl.when(j == 0)
@@ -200,62 +280,69 @@ def _emit_kernel(nv_ref, ib_ref, en_ref, bm_ref, q_ref, x_ref, r_ref,
         outi_ref[...] = jnp.zeros_like(outi_ref)
         # the carried below-r* counter starts at this shard's slot base
         # (zero single-device): emitted winners land in [base, base+n_lt_loc)
-        cnt_ref[:, 0:1] = sb_ref[...]
-        cnt_ref[:, 1:2] = jnp.zeros_like(cnt_ref[:, 1:2])
+        cnt_ref[0:1, :] = sb_ref[0]
+        cnt_ref[1:2, :] = jnp.zeros_like(cnt_ref[1:2, :])
 
-    r_star = r_ref[...]                             # (BQ, 1)
-
-    # block-min pruning composed with the enable mask: if the tile is
-    # outside the candidate set, or the nearest valid row in it is farther
-    # than the widest winning radius of any query in the block, no (q, x)
-    # pair here can emit — skip the re-stream entirely. Padded query rows
-    # carry r* = -1 and never raise the bound; skipping leaves the carried
-    # emit counts and all output slots untouched, so the skip is exact.
-    @pl.when((en_ref[0, 0] != 0) & (bm_ref[0, 0] <= jnp.max(r_star)))
+    # block-min pruning composed with the enable mask (the wrapper folds
+    # both into one run flag per tile): a tile outside the candidate set,
+    # or whose nearest valid row is farther than the widest winning radius
+    # of its query block, cannot emit — skip the re-stream entirely.
+    # Skipping leaves the carried emit counts and all output slots
+    # untouched, so the skip is exact.
+    @pl.when(run_ref[0, 0, j] != 0)
     def _work():
         n_valid = nv_ref[0]
         id_base = ib_ref[0]
-        q = q_ref[...]                              # (BQ, W)
-        x = x_ref[...]                              # (BN, W)
-        n_lt_total = nlt_ref[...]                   # (BQ, 1)
+        q = q_ref[...]                                     # (BQ, W)
+        r_star = r_ref[0]                                  # (1, BQ)
+        n_lt_total = nlt_ref[0]                            # (1, BQ)
         bq = q.shape[0]
-        slot_iota = jax.lax.broadcasted_iota(jnp.int32, (1, 1, k), 2)
-        base = j * bn
+        slot_ids = jax.lax.broadcasted_iota(jnp.int32, (k, _GROUP, bq), 0)
+        row = jax.lax.broadcasted_iota(jnp.int32, (sub, sub), 0)
+        col = jax.lax.broadcasted_iota(jnp.int32, (sub, sub), 1)
+        tri = jnp.where(col <= row, 1.0, 0.0).astype(jnp.bfloat16)
 
-        def body(s, carry):
-            cnt_lt, cnt_tie, od, oi = carry
-            xs = jax.lax.dynamic_slice_in_dim(x, s * sub, sub, axis=0)
-            dist = _tile_dist(q, xs, bins)                         # (BQ, sub)
-            gid = base + s * sub + jax.lax.broadcasted_iota(
-                jnp.int32, (1, sub), 1)
-            valid = gid < n_valid                                  # (1, sub)
+        def prefix(m):
+            """Inclusive count of set rows down the sublanes (index order)."""
+            m = jnp.where(m, 1.0, 0.0).astype(jnp.bfloat16)
+            return jnp.dot(tri, m, preferred_element_type=jnp.float32
+                           ).astype(jnp.int32)
+
+        def body(start, xs, carry):
+            cnt_lt, cnt_tie = carry                        # (1, BQ) each
+            dist = _tile_dist(q, xs, bins).T               # (sub, BQ)
+            first = j * bn + start
+            gid = first + jax.lax.broadcasted_iota(jnp.int32, (sub, 1), 0)
+            valid = gid < n_valid
             is_lt = valid & (dist < r_star)
             is_tie = valid & (dist == r_star)
             # slot of each winner: ids with dist < r* pack first (their
             # global count is < k by construction of r*), r*-ties fill the
             # remainder in index order; overflow ties land at slot k and
-            # match no output lane
-            rank_lt = cnt_lt + jnp.cumsum(is_lt.astype(jnp.int32), axis=1) - 1
-            rank_tie = (n_lt_total + cnt_tie
-                        + jnp.cumsum(is_tie.astype(jnp.int32), axis=1) - 1)
-            slot = jnp.where(is_lt, rank_lt, jnp.where(is_tie, rank_tie, k))
-            slot = jnp.minimum(slot, k)
-            onehot = (slot[:, :, None] == slot_iota).astype(jnp.int32)
-            od = od + jnp.sum(onehot * dist[:, :, None], axis=1)
-            oi = oi + jnp.sum(onehot * (gid + id_base)[:, :, None], axis=1)
-            cnt_lt = cnt_lt + jnp.sum(is_lt.astype(jnp.int32), axis=1,
-                                      keepdims=True)
-            cnt_tie = cnt_tie + jnp.sum(is_tie.astype(jnp.int32), axis=1,
-                                        keepdims=True)
-            return cnt_lt, cnt_tie, od, oi
+            # match no output slot
+            c_lt, c_tie = prefix(is_lt), prefix(is_tie)
+            rank_tie = n_lt_total + cnt_tie + c_tie - 1
+            slot = jnp.where(is_lt, cnt_lt + c_lt - 1,
+                             jnp.where(is_tie, rank_tie, k))
+            st_ref[...] = jnp.minimum(slot, k)
+            dt_ref[...] = dist
 
-        init = (cnt_ref[:, 0:1], cnt_ref[:, 1:2],
-                jnp.zeros((bq, k), jnp.int32), jnp.zeros((bq, k), jnp.int32))
-        cnt_lt, cnt_tie, od, oi = jax.lax.fori_loop(0, bn // sub, body, init)
-        outd_ref[...] += od
-        outi_ref[...] += oi
-        cnt_ref[:, 0:1] = cnt_lt
-        cnt_ref[:, 1:2] = cnt_tie
+            def emit(off):
+                hit = st_ref[pl.ds(off, _GROUP), :][None] == slot_ids
+                d8 = dt_ref[pl.ds(off, _GROUP), :]
+                g8 = (first + off + id_base + jax.lax.broadcasted_iota(
+                    jnp.int32, (_GROUP, bq), 0))
+                outd_ref[0] += jnp.where(hit, d8[None], 0)
+                outi_ref[0] += jnp.where(hit, g8[None], 0)
+
+            _scatter_groups(sub, emit)
+            return (cnt_lt + c_lt[sub - 1:sub, :],
+                    cnt_tie + c_tie[sub - 1:sub, :])
+
+        cnt_lt, cnt_tie = _sub_tiles(x_ref, bn, sub, body,
+                                     (cnt_ref[0:1, :], cnt_ref[1:2, :]))
+        cnt_ref[0:1, :] = cnt_lt
+        cnt_ref[1:2, :] = cnt_tie
 
 
 @functools.partial(jax.jit, static_argnames=("bins", "k", "bq", "bn", "sub",
@@ -297,49 +384,44 @@ def hamming_emit_pallas(q_packed: jax.Array, x_packed: jax.Array,
     N, _ = x_packed.shape
     bq, bn = min(bq, Q), min(bn, N)
     sub = min(sub, bn)
-    assert Q % bq == 0 and N % bn == 0 and bn % sub == 0, (Q, N, bq, bn, sub)
-    q32 = q_packed.astype(jnp.int32) if q_packed.dtype != jnp.int32 else q_packed
-    x32 = x_packed.astype(jnp.int32) if x_packed.dtype != jnp.int32 else x_packed
+    assert (Q % bq == 0 and N % bn == 0 and bn % sub == 0
+            and sub % _GROUP == 0), (Q, N, bq, bn, sub)
+    nq, nj = Q // bq, N // bn
     nv = jnp.full((1,), N, jnp.int32) if n_valid is None else (
         jnp.asarray(n_valid, jnp.int32).reshape(1))
     ib = (jnp.zeros((1,), jnp.int32) if id_base is None
           else jnp.asarray(id_base, jnp.int32).reshape(1))
-    bm = (jnp.zeros((Q // bq, N // bn), jnp.int32) if block_min is None
-          else block_min.astype(jnp.int32))
-    assert bm.shape == (Q // bq, N // bn), (bm.shape, Q // bq, N // bn)
-    en = (jnp.ones((Q // bq, N // bn), jnp.int32) if block_mask is None
-          else block_mask.astype(jnp.int32))
-    assert en.shape == (Q // bq, N // bn), (en.shape, Q // bq, N // bn)
-    r2 = r_star.astype(jnp.int32).reshape(Q, 1)
-    nlt2 = n_lt.astype(jnp.int32).reshape(Q, 1)
-    sb2 = (jnp.zeros((Q, 1), jnp.int32) if slot_base is None
-           else slot_base.astype(jnp.int32).reshape(Q, 1))
+    r1 = _as_i32(r_star).reshape(nq, 1, bq)
+    # one run flag per tile: enabled AND (block_min <= the widest r* of the
+    # query block). Padded query rows carry r* = -1 and never raise it.
+    run = jnp.ones((nq, nj), jnp.bool_)
+    if block_mask is not None:
+        run = run & (_tile_rows(block_mask, nq, nj)[:, 0] != 0)
+    if block_min is not None:
+        max_r = jnp.max(r1[:, 0], axis=1, keepdims=True)
+        run = run & (_tile_rows(block_min, nq, nj)[:, 0] <= max_r)
+    sb1 = (jnp.zeros((nq, 1, bq), jnp.int32) if slot_base is None
+           else _as_i32(slot_base).reshape(nq, 1, bq))
 
-    grid = (Q // bq, N // bn)
-    return pl.pallas_call(
+    vec = pl.BlockSpec((1, 1, bq), lambda i, j: (i, 0, 0))
+    out = pl.BlockSpec((1, k, _GROUP, bq), lambda i, j: (i, 0, 0, 0))
+    od8, oi8 = pl.pallas_call(
         functools.partial(_emit_kernel, bins=bins, k=k, sub=sub, bn=bn),
-        grid=grid,
+        grid=(nq, nj),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, 1), lambda i, j: (i, j),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, 1), lambda i, j: (i, j),
-                         memory_space=pltpu.SMEM),
+            _row_spec(nj),
             pl.BlockSpec((bq, W), lambda i, j: (i, 0)),
-            pl.BlockSpec((bn, W), lambda i, j: (j, 0)),
-            pl.BlockSpec((bq, 1), lambda i, j: (i, 0)),
-            pl.BlockSpec((bq, 1), lambda i, j: (i, 0)),
-            pl.BlockSpec((bq, 1), lambda i, j: (i, 0)),
+            pl.BlockSpec((W, bn), lambda i, j: (0, j)),
+            vec, vec, vec,
         ],
-        out_specs=[
-            pl.BlockSpec((bq, k), lambda i, j: (i, 0)),
-            pl.BlockSpec((bq, k), lambda i, j: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((Q, k), jnp.int32),
-            jax.ShapeDtypeStruct((Q, k), jnp.int32),
-        ],
-        scratch_shapes=[pltpu.VMEM((bq, 2), jnp.int32)],
+        out_specs=[out, out],
+        out_shape=[jax.ShapeDtypeStruct((nq, k, _GROUP, bq), jnp.int32)] * 2,
+        scratch_shapes=[pltpu.VMEM((_GROUP, bq), jnp.int32),
+                        pltpu.VMEM((sub, bq), jnp.int32),
+                        pltpu.VMEM((sub, bq), jnp.int32)],
         interpret=interpret,
-    )(nv, ib, en, bm, q32, x32, r2, nlt2, sb2)
+    )(nv, ib, _tile_rows(run, nq, nj), *_codes(q_packed, x_packed), r1,
+      _as_i32(n_lt).reshape(nq, 1, bq), sb1)
+    return _unblock(od8), _unblock(oi8)

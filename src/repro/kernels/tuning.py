@@ -18,14 +18,17 @@ measuring path is testable without wall-clock assertions).
 ``cost_hints`` reports which side won as ``hint_source`` ("measured" |
 "default"), which ``QueryPlan.explain()`` surfaces.
 
-The governing budget on TPU is VMEM: each grid cell holds the code tiles
-(bq + bn) * W words plus the kernels' widest intermediate — the
-(bq, sub, lanes) one-hot used for the histogram scatter / slot scatter,
-where ``lanes`` is `bins` for pass 1 and `k` for pass 2. We size ``sub`` so
-that intermediate stays under ~2 MiB, keep bq a sublane multiple (8) and bn
-a lane multiple (128), and stream the dataset in the largest bn that still
-double-buffers. On CPU the kernels run interpreted (the grid lowers to an
-XLA loop), so smaller tiles bound trace size instead of VMEM.
+The governing budget on TPU is VMEM, counted in padded (8, 128) tiles:
+each grid cell holds the transposed (W, bn) code tile — W rounds up to 8
+sublanes — plus the kernels' widest intermediate, the (lanes, 8, bq)
+one-hot used for the histogram scatter / slot scatter, where ``lanes`` is
+`bins` for pass 1 and `k` for pass 2 and bq rounds up to 128 lanes. We keep
+that one-hot under ~2 MiB by shrinking bq, keep bq a sublane multiple (8),
+take sub = 128 (the lane width: the kernels slice the code tile at sub
+offsets along the lanes) and bn a sub multiple, and stream the dataset in
+the largest bn that still double-buffers. On CPU the kernels run
+interpreted (the grid lowers to an XLA loop), so smaller tiles bound trace
+size instead of VMEM.
 
 Since the fused select went single-shot (one Pallas grid owns ALL of N —
 no engine-side chunk scan), the heuristic is also grid-wide aware: N/bn is
@@ -45,17 +48,19 @@ import jax
 
 _SUBLANE = 8
 _LANE = 128
-# per-cell budget for the (bq, sub, lanes) int32 one-hot intermediate.
+# per-cell budget for the (lanes, 8, bq) int32 one-hot intermediate.
 # CPU runs interpreted: no VMEM to respect, and runtime scales with the
 # number of in-kernel iterations, so a fatter budget (bigger sub, fewer
 # fori steps) is strictly faster there.
 _ONEHOT_BYTES = {"tpu": 2 << 20, "cpu": 4 << 20, "gpu": 1 << 20}
 # single-shot grids: cap the N-block count (summary second dim / grid
-# extent per query block) by growing bn, up to this (bn, W) int32 code-tile
-# VMEM budget. On TPU the grid is a hardware loop, so the cap only bounds
-# the summary; interpreted (CPU) the grid UNROLLS into the program, so the
-# cap is much tighter there — the in-cell fori over bn/sub stays rolled,
-# making a big bn the cheap direction.
+# extent per query block) by growing bn, up to this (W, bn) int32 code-tile
+# VMEM budget. At the cap, the per-query-block SMEM rows of the
+# mask/summary/run flags hold 1024 int32 (4 KiB) each. On TPU the grid is
+# a hardware loop, so the cap only bounds the summary; interpreted (CPU)
+# the grid UNROLLS into the program, so the cap is much tighter there —
+# the in-cell fori over bn/sub stays rolled, making a big bn the cheap
+# direction.
 _MAX_N_BLOCKS = {"tpu": 1024, "cpu": 16, "gpu": 1024}
 _CODE_TILE_BYTES = {"tpu": 4 << 20, "cpu": 1 << 20, "gpu": 2 << 20}
 
@@ -165,7 +170,8 @@ def configure(path: str | None = None) -> AutotuneCache:
     return _CACHE
 
 
-def _sane_topk_entry(entry: dict, N: int) -> tuple[int, int, int] | None:
+def _sane_topk_entry(entry: dict, N: int,
+                     backend: str) -> tuple[int, int, int] | None:
     """Sanitize a measured (bq, bn, sub) back onto the kernels' tiling
     constraints; None when the entry is not a usable shape."""
     try:
@@ -175,7 +181,9 @@ def _sane_topk_entry(entry: dict, N: int) -> tuple[int, int, int] | None:
     if min(bq, bn, sub) <= 0:
         return None
     bq = _round_up(bq, _SUBLANE)
-    sub = min(_round_up(sub, _SUBLANE), 256)
+    # TPU: sub-tiles are lane slices of the (W, bn) code tile
+    sub = (_LANE if backend == "tpu"
+           else min(_round_up(sub, _SUBLANE), 256))
     bn = _round_up(bn, sub)
     return bq, bn, sub
 
@@ -187,7 +195,8 @@ def hint_source(backend: str, kind: str, Q: int, N: int, W: int,
     ent = _CACHE.get(backend, kind, Q, N, W, lanes)
     if kind == "topk":
         return "measured" if (ent is not None
-                              and _sane_topk_entry(ent, N)) else "default"
+                              and _sane_topk_entry(ent, N, backend)
+                              ) else "default"
     return "measured" if (ent is not None and ent.get("bn")) else "default"
 
 
@@ -238,7 +247,8 @@ def topk_candidates(Q: int, N: int, W: int, lanes: int,
            (max(bq // 2, _SUBLANE), bn, sub)]
     out, seen = [], set()
     for cand in raw:
-        ok = _sane_topk_entry(dict(zip(("bq", "bn", "sub"), cand)), N)
+        ok = _sane_topk_entry(dict(zip(("bq", "bn", "sub"), cand)), N,
+                              backend)
         if ok and ok not in seen:
             seen.add(ok)
             out.append(dict(zip(("bq", "bn", "sub"), ok)))
@@ -262,7 +272,7 @@ def topk_blocks(Q: int, N: int, W: int, lanes: int,
     backend = backend or jax.default_backend()
     ent = _CACHE.get(backend, "topk", Q, N, W, lanes)
     if ent is not None:
-        sane = _sane_topk_entry(ent, N)
+        sane = _sane_topk_entry(ent, N, backend)
         if sane is not None:
             return sane
     return _topk_blocks_default(Q, N, W, lanes, backend)
@@ -272,29 +282,35 @@ def _topk_blocks_default(Q: int, N: int, W: int, lanes: int,
                          backend: str) -> tuple[int, int, int]:
     """The static VMEM heuristic — the cache's seeded default."""
     budget = _ONEHOT_BYTES.get(backend, 1 << 20)
+    tpu = backend == "tpu"
+    lanes = max(lanes, 1)
 
-    bq = min(_round_up(Q, _SUBLANE), 64 if backend == "tpu" else 32)
-    # one-hot (bq, sub, lanes) int32 under budget; sub a sublane multiple
-    sub = _round_down(budget // (4 * bq * max(lanes, 1)), _SUBLANE)
-    sub = min(sub, 256)
-    # extreme lanes (bins or k in the thousands): the sublane floor on sub
-    # would silently bust the budget — shrink bq instead (it only amortizes
-    # the revisited output block). The (8, 8, lanes) floor is the hard
-    # minimum tile.
-    while bq > _SUBLANE and 4 * bq * sub * max(lanes, 1) > budget:
+    # queries run along the lanes of the kernels' transposed tiles: on TPU
+    # a full 128-lane query block (or the whole padded batch, if smaller)
+    bq = min(_round_up(Q, _SUBLANE), _LANE if tpu else 32)
+    # one-hot (lanes, 8, bq) int32 under budget, bq counted lane-padded;
+    # extreme lanes (bins or k in the thousands) shrink bq (it only
+    # amortizes the revisited output block) down to one sublane group
+    padded = (lambda b: _round_up(b, _LANE)) if tpu else (lambda b: b)
+    while bq > _SUBLANE and 4 * lanes * _SUBLANE * padded(bq) > budget:
         bq = _round_down(bq // 2, _SUBLANE)
+    if tpu:
+        sub = _LANE
+    else:
+        sub = min(_round_down(budget // (4 * bq * lanes), _SUBLANE), 256)
     # stream the dataset in big tiles: amortize the revisited output block
-    bn_cap = 2048 if backend == "tpu" else 512
+    bn_cap = 2048 if tpu else 512
     bn = min(_round_up(N, sub), _round_down(bn_cap, sub))
     # single-shot whole-datastore grid: once N/bn exceeds the block cap the
     # pruning summary and grid length dominate — grow bn (still a multiple
-    # of sub) until the block count is bounded or the code tile hits its
-    # VMEM budget
+    # of sub) until the block count is bounded or the (W, bn) code tile,
+    # W padded to 8 sublanes, hits its VMEM budget
     max_blocks = _MAX_N_BLOCKS.get(backend, 64)
     if N > bn * max_blocks:
         want = _round_up(-(-N // max_blocks), sub)
+        rows = _round_up(max(W, 1), _SUBLANE) if tpu else max(W, 1)
         cap = _round_down(_CODE_TILE_BYTES.get(backend, 1 << 20)
-                          // (4 * max(W, 1)), sub)
+                          // (4 * rows), sub)
         bn = max(bn, min(want, cap))
     return bq, bn, sub
 
@@ -365,7 +381,7 @@ def cost_hints(Q: int, N: int, W: int, lanes: int, *, path: str = "fused",
         hints = {
             "bq": bq, "bn": bn, "sub": sub, "grid": list(grid),
             "codes_bytes_streamed": 2 * 4 * W * n_pad * grid[0],
-            "onehot_bytes": 4 * bq * sub * max(lanes, 1),
+            "onehot_bytes": 4 * max(lanes, 1) * _SUBLANE * bq,
             "summary_bytes": 4 * grid[0] * grid[1],
             "hist_bytes": 4 * Q * max(lanes, 1),
             "hint_source": hint_source(backend, "topk", Q, n_eff, W, lanes),

@@ -28,7 +28,7 @@ from repro.configs import (ALL_ARCHS, TrainConfig, get_config, get_shape,
                            runnable_cells, SHAPES, StepKind)
 from repro.dist import steps as steps_mod
 from repro.launch import hlo, jaxpr_analysis, roofline
-from repro.launch.mesh import HBM_BYTES, make_production_mesh
+from repro.launch.mesh import PRODUCTION_KIND, make_production_mesh, peaks
 from repro.launch.specs import input_specs
 
 
@@ -103,10 +103,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool = False,
 
     mem = compiled.memory_analysis()
     print(mem)                                    # proves it fits
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):  # older jax: list of per-program dicts
-        cost = cost[0] if cost else {}
-    cost = cost or {}
+    cost = compiled.cost_analysis() or {}
     print({k: cost[k] for k in ("flops", "bytes accessed") if k in cost})
     mem_stats = {
         "argument_bytes": getattr(mem, "argument_size_in_bytes", 0),
@@ -118,7 +115,8 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool = False,
     mem_stats["per_device_bytes"] = (
         (mem_stats["argument_bytes"] - mem_stats["alias_bytes"]) / chips
         + mem_stats["temp_bytes"])
-    mem_stats["fits_hbm"] = mem_stats["per_device_bytes"] < HBM_BYTES
+    mem_stats["fits_hbm"] = (mem_stats["per_device_bytes"]
+                             < peaks(PRODUCTION_KIND)["hbm_bytes"])
 
     hlo_text = compiled.as_text()
     if hlo_path:

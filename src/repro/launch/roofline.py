@@ -5,9 +5,12 @@ loop-aware HLO parser in launch/hlo.py supplies flops / HBM bytes /
 collective bytes — ``compiled.cost_analysis()`` is both loop-blind and
 collective-blind, which we verified empirically; see EXPERIMENTS.md).
 
-  compute    = flops_per_device / 197 TFLOP/s
-  memory     = hbm_bytes_per_device / 819 GB/s
-  collective = collective_bytes_per_device / 50 GB/s   (1 ICI link charged)
+  compute    = flops_per_device / peak bf16 FLOP/s
+  memory     = hbm_bytes_per_device / peak HBM bytes/s
+  collective = collective_bytes_per_device / ICI bytes/s (1 link charged)
+
+with the peaks of the target ``device_kind`` from ``launch/mesh.PEAKS``
+(197 TFLOP/s, 819 GB/s and 50 GB/s on a v5e chip).
 
 MODEL_FLOPS = 6*N_active*D (train) / 2*N_active*D (forward-only) + the causal
 attention term — the useful-compute yardstick; useful_ratio compares it with
@@ -19,7 +22,7 @@ import dataclasses
 from typing import Dict, Optional
 
 from repro.configs.base import ModelConfig, ShapeConfig, StepKind
-from repro.launch.mesh import HBM_BW, ICI_BW, PEAK_FLOPS_BF16
+from repro.launch import mesh
 from repro.models import lm
 
 
@@ -75,20 +78,22 @@ class RooflineReport:
 
 def build_report(cfg: ModelConfig, shape: ShapeConfig, mesh_name: str,
                  chips: int, stats: Dict, memory_stats=None,
-                 cost_flops: Optional[float] = None) -> RooflineReport:
+                 cost_flops: Optional[float] = None,
+                 device_kind: str = mesh.PRODUCTION_KIND) -> RooflineReport:
+    peak = mesh.peaks(device_kind)
     flops = float(stats["flops"])
     byts = float(stats["io_bytes"])
     coll = stats["coll_bytes"]
-    compute_s = flops / PEAK_FLOPS_BF16
-    memory_s = byts / HBM_BW
-    collective_s = float(coll.get("total", 0.0)) / ICI_BW
+    compute_s = flops / peak["bf16_flops"]
+    memory_s = byts / peak["hbm_bw"]
+    collective_s = float(coll.get("total", 0.0)) / peak["ici_bw"]
     terms = {"compute": compute_s, "memory": memory_s, "collective": collective_s}
     dominant = max(terms, key=terms.get)
     bound = max(terms.values())
     model_flops = analytic_model_flops(cfg, shape)
     useful = model_flops / (chips * flops) if flops else 0.0
     # fraction of roofline: time the useful work needs at peak vs the bound
-    ideal_s = model_flops / (chips * PEAK_FLOPS_BF16)
+    ideal_s = model_flops / (chips * peak["bf16_flops"])
     frac = ideal_s / bound if bound > 0 else 0.0
     return RooflineReport(
         arch=cfg.name, shape=shape.name, mesh=mesh_name, chips=chips,

@@ -282,6 +282,8 @@ def test_topk_blocks_divisibility():
         bq, bn, sub = tuning.topk_blocks(Q, N, W, lanes, backend="cpu")
         assert bq % 8 == 0 and bn % sub == 0 and sub % 8 == 0
         bq_t, bn_t, sub_t = tuning.topk_blocks(Q, N, W, lanes, backend="tpu")
-        assert bn_t % sub_t == 0
-        # one-hot intermediate respects the VMEM budget
-        assert 4 * bq_t * sub_t * lanes <= (2 << 20)
+        assert bn_t % sub_t == 0 and sub_t % 128 == 0 and bq_t % 8 == 0
+        # (lanes, 8, bq) one-hot, bq padded to 128 lanes, and the (W, bn)
+        # code tile, W padded to 8 sublanes, respect their VMEM budgets
+        assert 4 * lanes * 8 * (-(-bq_t // 128) * 128) <= (2 << 20)
+        assert 4 * 8 * bn_t <= (4 << 20)
