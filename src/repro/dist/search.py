@@ -116,7 +116,8 @@ class FaultTolerantSearch:
                 self._held[u].add(i)
         self.counters = {"failovers": 0, "restarts": 0, "rebuilt_ranges": 0,
                          "searches": 0, "degraded_searches": 0}
-        self._warm: set = set()
+        self._row = (codes.shape[1], jax.dtypes.canonicalize_dtype(codes.dtype))
+        self._exe: Dict[tuple, object] = {}
 
     # -- fault plumbing ----------------------------------------------------
 
@@ -124,25 +125,26 @@ class FaultTolerantSearch:
         if self.injector is not None:
             self.injector.check(site, unit)
 
+    def _compiled(self, fn, q, range_idx: int):
+        """``fn`` compiled ahead of time for ``q`` against one range's rows,
+        once per distinct shape. A compile is set-up, not shard latency: it
+        happens here, before any timed attempt, and executes nothing."""
+        width, dtype = self._row
+        rows = jax.ShapeDtypeStruct((self.map.counts[range_idx], width), dtype)
+        key = (fn, q.shape, rows.shape)
+        exe = self._exe.get(key)
+        if exe is None:
+            exe = self._exe[key] = fn.lower(q, rows).compile()
+        return exe
+
     def _call_unit(self, site: str, range_idx: int, fn_for,
-                   serving: set, shape=None) -> Optional[Tuple[str, object]]:
+                   serving: set) -> Optional[Tuple[str, object]]:
         """Run ``fn_for(unit)`` on the range's serving holder, failing over
         through the replica chain as the registry declares units dead.
         Every attempt is deadline-timed into the registry — persistent
         failures walk a unit healthy -> suspect -> dead, which is exactly
         what reroutes the range to its next holder. Returns (unit, result)
-        or None when no live holder remains (coverage change).
-
-        The first call of a step at a new ``shape`` compiles it on the
-        host. That is set-up time, not shard latency: it runs once untimed
-        (and outside fault injection), so a compile never counts as a
-        deadline miss."""
-        warm = (site, range_idx, shape)
-        if shape is not None and warm not in self._warm:
-            unit = self.map.owner(range_idx, serving, held=self._held)
-            if unit is not None:
-                fn_for(unit)
-                self._warm.add(warm)
+        or None when no live holder remains (coverage change)."""
         tried_dead = set()
         while True:
             unit = self.map.owner(range_idx, serving - tried_dead,
@@ -237,10 +239,11 @@ class FaultTolerantSearch:
         hists = []
         for i in covered:
             hist_fn, _ = steps_mod.unit_search_steps(self.bins, k_k)
+            hist_fn = self._compiled(hist_fn, q, i)
             got = self._call_unit(
                 "shard_hist", i,
                 lambda u, i=i, f=hist_fn: np.asarray(f(q, self._data[u][i])),
-                serving, shape=(Q, k_k))
+                serving)
             if got is None:
                 raise _CoverageChanged(f"range {i} lost during hist")
             hists.append(got[1].astype(np.int64))
@@ -256,11 +259,12 @@ class FaultTolerantSearch:
         for i in covered:
             k_loc = min(k, self.map.counts[i])
             _, topk_fn = steps_mod.unit_search_steps(self.bins, k_loc)
+            topk_fn = self._compiled(topk_fn, q, i)
             got = self._call_unit(
                 "shard_emit", i,
                 lambda u, i=i, f=topk_fn: tuple(
                     np.asarray(a) for a in f(q, self._data[u][i])),
-                serving, shape=(Q, k_loc))
+                serving)
             if got is None:
                 raise _CoverageChanged(f"range {i} lost during emit")
             ld, li = got[1]

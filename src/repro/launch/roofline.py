@@ -9,7 +9,7 @@ collective-blind, which we verified empirically; see EXPERIMENTS.md).
   memory     = hbm_bytes_per_device / peak HBM bytes/s
   collective = collective_bytes_per_device / ICI bytes/s (1 link charged)
 
-with the peaks of the target ``device_kind`` from ``launch/mesh.PEAKS``
+with the peaks of ``launch/mesh.PRODUCTION_KIND`` from ``launch/mesh.PEAKS``
 (197 TFLOP/s, 819 GB/s and 50 GB/s on a v5e chip).
 
 MODEL_FLOPS = 6*N_active*D (train) / 2*N_active*D (forward-only) + the causal
@@ -78,9 +78,8 @@ class RooflineReport:
 
 def build_report(cfg: ModelConfig, shape: ShapeConfig, mesh_name: str,
                  chips: int, stats: Dict, memory_stats=None,
-                 cost_flops: Optional[float] = None,
-                 device_kind: str = mesh.PRODUCTION_KIND) -> RooflineReport:
-    peak = mesh.peaks(device_kind)
+                 cost_flops: Optional[float] = None) -> RooflineReport:
+    peak = mesh.peaks(mesh.PRODUCTION_KIND)
     flops = float(stats["flops"])
     byts = float(stats["io_bytes"])
     coll = stats["coll_bytes"]

@@ -397,6 +397,57 @@ def test_fts_injected_faults_drive_failover(corpus):
     assert inj.fired.get("shard_hist@unit0", 0) >= 1
 
 
+def test_fts_transient_first_call_fails_over(corpus):
+    """A unit step that raises a transient error on its first calls at a
+    new shape walks that unit to dead, and the range fails over to its
+    replica: no first call runs outside the fault handling."""
+    from repro.dist.search import reference_over_covered
+    codes, q, counts, N = corpus
+    fts = _fts(codes, counts, factor=2)
+    compiled, failures = fts._compiled, [fts.registry.dead_after]
+
+    def flaky(fn, qq, range_idx):
+        exe = compiled(fn, qq, range_idx)
+
+        def call(*args):
+            if failures[0] > 0:
+                failures[0] -= 1
+                raise TimeoutError("unit step timed out")
+            return exe(*args)
+        return call
+
+    fts._compiled = flaky
+    dd, ii, rep = fts.search(q[:3], 16)
+    rd, ri = reference_over_covered(codes, q[:3], 16, 64, np.arange(N))
+    assert np.array_equal(dd, rd) and np.array_equal(ii, ri)
+    assert rep.coverage_frac == 1.0 and rep.dead_shards == ("unit0",)
+    assert fts.registry.state("unit0") == DEAD
+    assert fts.counters["failovers"] == 1
+
+
+def test_fts_compile_is_not_shard_latency(corpus, monkeypatch):
+    """Compiling a unit step at a new shape is set-up: a compile that takes
+    far longer than the per-call deadline marks no unit suspect."""
+    import jax
+    from repro.dist.search import reference_over_covered
+    codes, q, counts, N = corpus
+    now = [0.0]
+    compile_ = jax.stages.Lowered.compile
+
+    def slow_compile(self, *args, **kwargs):
+        now[0] += 10.0
+        return compile_(self, *args, **kwargs)
+
+    monkeypatch.setattr(jax.stages.Lowered, "compile", slow_compile)
+    fts = _fts(codes, counts, deadline_s=0.25, clock=lambda: now[0])
+    dd, ii, rep = fts.search(q, 16)
+    rd, ri = reference_over_covered(codes, q, 16, 64, np.arange(N))
+    assert np.array_equal(dd, rd) and np.array_equal(ii, ri)
+    assert now[0] >= 10.0 and rep.complete
+    assert all(fts.registry.state(u) == HEALTHY for u in fts.map.units)
+    assert fts.counters["failovers"] == 0
+
+
 def test_fts_merge_faults_retry_exactly(corpus):
     from repro.dist.search import reference_over_covered
     from repro.runtime import faults
