@@ -126,13 +126,16 @@ class KNNEngine(NamedTuple):
 
     def search(self, q_packed: jax.Array, k: int,
                chunk: int = plan_mod.DEFAULT_CHUNK,
-               method: str = DistanceMethod.XOR, select: str = "auto"):
+               method: str = DistanceMethod.XOR, select: str = "auto",
+               return_stats: bool = False):
+        """(dists, ids) of the plan ``query_plan`` gives; ``return_stats``
+        (fused and fused_scan plans) appends the kernels' tile counts."""
         if select != "auto":
             plan_mod._warn_legacy("KNNEngine.search", "select", select)
         p = self.query_plan(q_packed, k, chunk=chunk, method=method,
                             select=select)
         return plan_mod.execute(p, q_packed, codes=self.codes,
-                                layout=self.layout)
+                                layout=self.layout, return_stats=return_stats)
 
 
 # ---------------------------------------------------------------------------
@@ -145,7 +148,8 @@ def search_sharded(codes_packed: jax.Array, q_packed: jax.Array, k: int, d: int,
                    method: str = DistanceMethod.XOR,
                    select: str = "auto", reorder_local: bool = False,
                    merge: Optional[str] = None, fanout: int = 0,
-                   shard_n_valid=None, shard_participate=None):
+                   shard_n_valid=None, shard_participate=None,
+                   return_stats: bool = False):
     """Datastore sharded over ``axes`` (cardinality sharding); queries
     replicated. A thin plan-builder: the planner decides the merge
     strategy, the executor runs it.
@@ -187,6 +191,10 @@ def search_sharded(codes_packed: jax.Array, q_packed: jax.Array, k: int, d: int,
     mask (hist-family merges only) — dead shards' rows are excluded
     exactly and ids renumber over the survivors, the degraded-but-exact
     answer of the shard-fault-tolerance layer.
+
+    ``return_stats=True`` (fused select, hist-family merge) appends the
+    kernels' tile counts: per shard as (n_shards,) arrays and summed
+    (``plan._execute_sharded``).
     """
     if select != "auto":
         plan_mod._warn_legacy("search_sharded", "select", select)
@@ -202,7 +210,8 @@ def search_sharded(codes_packed: jax.Array, q_packed: jax.Array, k: int, d: int,
                               uneven=shard_n_valid is not None)
     return plan_mod.execute(p, q_packed, codes=codes_packed, mesh=mesh,
                             shard_n_valid=shard_n_valid,
-                            shard_participate=shard_participate)
+                            shard_participate=shard_participate,
+                            return_stats=return_stats)
 
 
 def shard_datastore(codes_packed: jax.Array, mesh: Mesh, axes: Sequence[str]):

@@ -129,7 +129,14 @@ def build_layout(codes: jax.Array, d: int, n_buckets: int | None = None,
     """Build a bucket-clustered layout. With ``assign`` (e.g. k-means/IVF
     cluster ids) the reorder follows the index's own buckets (``n_buckets``
     defaults to max(assign) + 1); without, the pure-Hamming prefix fallback
-    buckets by LSH key — no float vectors. Build-time (host) only."""
+    buckets by LSH key — no float vectors. Build-time (host) only; the
+    build is the ``knn.layout.build`` span of a profiler trace."""
+    with jax.profiler.TraceAnnotation("knn.layout.build"):
+        return _build_layout(codes, d, n_buckets, assign)
+
+
+def _build_layout(codes: jax.Array, d: int, n_buckets: int | None,
+                  assign: jax.Array | None) -> BucketLayout:
     if assign is None:
         bits = (n_buckets - 1).bit_length() if n_buckets else (
             default_bits(codes.shape[0]))
@@ -263,8 +270,10 @@ def masked_topk(layout: BucketLayout, q_packed: jax.Array, k: int, d: int,
 
     Returns (dists, ids[, stats]): (Q, k) ascending, ORIGINAL ids, -1 in
     sentinel slots — the same contract as ``index._scan_candidates`` over
-    the rows the mask enables. Block sizes default to
-    ``tuning.layout_blocks`` (bn aligned to the mean bucket size)."""
+    the rows the mask enables. ``stats`` is ``ops.hamming_topk``'s: the
+    tiles the two kernels ran, counted from the enable mask and run flags
+    they were handed. Block sizes default to ``tuning.layout_blocks`` (bn
+    aligned to the mean bucket size)."""
     from repro.kernels import ops, tuning
 
     Q, W = q_packed.shape
@@ -289,7 +298,8 @@ def masked_topk(layout: BucketLayout, q_packed: jax.Array, k: int, d: int,
                            block_mask=mask, bq=bq, bn=bn, sub=sub,
                            return_stats=return_stats)
     dd, ii = out[0], out[1]
-    ids = original_ids(layout, dd, ii, d)
+    with jax.named_scope("knn.layout.map_ids"):
+        ids = original_ids(layout, dd, ii, d)
     return (dd, ids, out[2]) if return_stats else (dd, ids)
 
 

@@ -874,12 +874,14 @@ def _auto_chunk(chunk: int, d: int) -> int:
 
 
 def _scan_select(codes_packed: jax.Array, q_packed: jax.Array, k: int,
-                 plan: QueryPlan, id_offset: jax.Array | int = 0
-                 ) -> Tuple[jax.Array, jax.Array]:
+                 plan: QueryPlan, id_offset: jax.Array | int = 0,
+                 return_stats: bool = False):
     """The full-scan select stage (former ``engine.search_chunked`` body).
 
     codes: (N, W) uint32, q: (Q, W); returns (dists (Q, k) ascending,
-    global ids (Q, k)). All select paths are bit-identical at any chunk."""
+    global ids (Q, k)). All select paths are bit-identical at any chunk.
+    ``return_stats`` (fused selects only) appends the kernels' tile counts,
+    summed over chunks on ``fused_scan``."""
     sel = plan.select
     N, W = codes_packed.shape
     Q = q_packed.shape[0]
@@ -888,8 +890,9 @@ def _scan_select(codes_packed: jax.Array, q_packed: jax.Array, k: int,
     if sel.path == "fused":
         from repro.kernels import ops
 
-        bd, bi = ops.hamming_topk(q_packed, codes_packed, k, d + 1)
-        return bd, bi + id_offset
+        out = ops.hamming_topk(q_packed, codes_packed, k, d + 1,
+                               return_stats=return_stats)
+        return (out[0], out[1] + id_offset, *out[2:])
 
     if sel.path == "approx":
         from repro.kernels import approx_select
@@ -915,14 +918,20 @@ def _scan_select(codes_packed: jax.Array, q_packed: jax.Array, k: int,
     if sel.path == "fused_scan":
         from repro.kernels import ops
 
+        chunk_tiles = []        # the grid one chunk runs (static)
+
         def body(carry, xs):
             best_d, best_i = carry
             ci, codes_c = xs
             n_valid = jnp.clip(N - ci * chunk, 0, chunk)
-            cd, cidx = ops.hamming_topk(q_packed, codes_c, min(k, chunk),
-                                        d + 1, n_valid=n_valid)
-            best_d, best_i = topk.merge_topk(best_d, best_i, cd,
-                                             cidx + ci * chunk, k)
+            out = ops.hamming_topk(q_packed, codes_c, min(k, chunk), d + 1,
+                                   n_valid=n_valid, return_stats=return_stats)
+            best_d, best_i = topk.merge_topk(best_d, best_i, out[0],
+                                             out[1] + ci * chunk, k)
+            if return_stats:
+                chunk_tiles.append(out[2]["blocks_total"])
+                return (best_d, best_i), (out[2]["p1_blocks_skipped"],
+                                          out[2]["blocks_skipped"])
             return (best_d, best_i), None
     else:
         select_fn = {"composite": topk.composite_topk,
@@ -943,7 +952,14 @@ def _scan_select(codes_packed: jax.Array, q_packed: jax.Array, k: int,
             return (best_d, best_i), None
 
     init = (jnp.full((Q, k), d + 1, jnp.int32), jnp.full((Q, k), N, jnp.int32))
-    (bd, bi), _ = jax.lax.scan(body, init, (jnp.arange(n_chunks), chunks))
+    (bd, bi), skipped = jax.lax.scan(body, init,
+                                     (jnp.arange(n_chunks), chunks))
+    if return_stats:
+        # summed over the chunks, each of which runs the same grid
+        return bd, bi + id_offset, {
+            "blocks_total": n_chunks * chunk_tiles[0],
+            "blocks_skipped": jnp.sum(skipped[1]),
+            "p1_blocks_skipped": jnp.sum(skipped[0])}
     return bd, bi + id_offset
 
 
@@ -964,8 +980,8 @@ def gather_scan(codes: jax.Array, q_packed: jax.Array, cand: jax.Array,
 
 
 def _execute_sharded(plan: QueryPlan, q_packed: jax.Array, codes: jax.Array,
-                     mesh: Mesh, shard_n_valid=None, shard_participate=None
-                     ) -> Tuple[jax.Array, jax.Array]:
+                     mesh: Mesh, shard_n_valid=None, shard_participate=None,
+                     return_stats: bool = False):
     """The sharded merge stage.
 
     ``strategy in HIST_STRATEGIES``: the distributed counting select
@@ -987,7 +1003,13 @@ def _execute_sharded(plan: QueryPlan, q_packed: jax.Array, codes: jax.Array,
     zeroed inside the kernels and ids renumber over the survivors, so the
     result is bit-identical to a from-scratch search over a store holding
     only the surviving shards' valid rows (hist-family strategies only;
-    composes with ``shard_n_valid``)."""
+    composes with ``shard_n_valid``).
+
+    ``return_stats`` (fused select, hist-family merge): each shard's tile
+    counts leave ``shard_map`` as one (n_shards,) array per count
+    (``shard_blocks_skipped``, ``shard_p1_blocks_skipped``: no
+    collective), beside their sums under the ``tile_stats`` keys;
+    ``shard_blocks_total`` is one shard's grid."""
     axes = plan.merge.axes
     k, k_local = plan.k, plan.merge.k_local
     n_dev = 1
@@ -1058,10 +1080,16 @@ def _execute_sharded(plan: QueryPlan, q_packed: jax.Array, codes: jax.Array,
                     recall_target=plan.select.recall_target,
                     n_valid=nv, id_base=ib, n_total=nt, perm=perm_l,
                     participate=part_all, tree_fanout=tree_fanout)
-            return ops.hamming_topk_sharded(
+            out = ops.hamming_topk_sharded(
                 q, codes_l, k, plan.d + 1, axes, n_shards=n_dev,
                 n_valid=nv, id_base=ib, n_total=nt, perm=perm_l,
-                participate=part_all, tree_fanout=tree_fanout)
+                participate=part_all, tree_fanout=tree_fanout,
+                return_stats=return_stats)
+            if return_stats:
+                shard_tiles.append(out[2]["blocks_total"])
+                return out[:2] + (out[2]["p1_blocks_skipped"].reshape(1),
+                                  out[2]["blocks_skipped"].reshape(1))
+            return out
         if nv is not None:
             # uneven shards on the legacy merge: mask padding in-kernel,
             # report ids in the unpadded global space, sentinels at the
@@ -1109,11 +1137,24 @@ def _execute_sharded(plan: QueryPlan, q_packed: jax.Array, codes: jax.Array,
                  .astype(jnp.int32)], axis=1)
         return sd[:, :k], order[:, :k]
 
-    mapped = shard_map(
-        local, mesh=mesh,
-        in_specs=(P(axes, None), P(None, None)),
-        out_specs=(P(None, None), P(None, None)))
-    return mapped(codes, q_packed)
+    shard_tiles = []            # one shard's grid (static)
+    out_specs = (P(None, None), P(None, None))
+    if return_stats:
+        out_specs += (P(axes), P(axes))
+    mapped = shard_map(local, mesh=mesh,
+                       in_specs=(P(axes, None), P(None, None)),
+                       out_specs=out_specs)
+    out = mapped(codes, q_packed)
+    if return_stats:
+        p1, p2 = out[2], out[3]
+        return out[0], out[1], {
+            "blocks_total": n_dev * shard_tiles[0],
+            "blocks_skipped": jnp.sum(p2),
+            "p1_blocks_skipped": jnp.sum(p1),
+            "shard_blocks_total": shard_tiles[0],
+            "shard_blocks_skipped": p2,
+            "shard_p1_blocks_skipped": p1}
+    return out
 
 
 def execute(plan: QueryPlan, q_packed: jax.Array, *,
@@ -1137,20 +1178,23 @@ def execute(plan: QueryPlan, q_packed: jax.Array, *,
     ``layout`` (+ ``probe`` bucket ids and/or ``cand_ids`` original ids,
     core/layout.py semantics); gather candidates need ``codes`` + ``cand``
     ((Q, C) int32, -1 padded); full scans need ``codes`` (plus ``layout``
-    when the plan streams a prebuilt one). ``return_stats`` (masked plans
-    only) appends the pruning telemetry."""
+    when the plan streams a prebuilt one). ``return_stats`` appends the
+    fused kernels' tile counts (``ops.tile_stats``; per shard too on a
+    sharded plan): every plan whose select is ``fused`` or ``fused_scan``
+    has them, sharded plans through a hist-family merge."""
+    if return_stats:
+        _check_stats(plan)
     if plan.merge.kind == "sharded":
         assert mesh is not None and codes is not None
         return _execute_sharded(plan, q_packed, codes, mesh,
                                 shard_n_valid=shard_n_valid,
-                                shard_participate=shard_participate)
+                                shard_participate=shard_participate,
+                                return_stats=return_stats)
     if plan.candidates.kind == "block_mask":
         assert layout is not None
         if plan.select.path == "approx":
             from repro.kernels import approx_select
 
-            assert not return_stats, \
-                "pruning stats only exist on the fused masked path"
             return approx_select.masked_approx_topk(
                 layout, q_packed, plan.k, plan.d, probe=probe,
                 cand_ids=cand_ids,
@@ -1158,21 +1202,39 @@ def execute(plan: QueryPlan, q_packed: jax.Array, *,
         return layout_mod.masked_topk(layout, q_packed, plan.k, plan.d,
                                       probe=probe, cand_ids=cand_ids,
                                       return_stats=return_stats)
-    assert not return_stats, "stats only exist on the masked path"
     if plan.candidates.kind == "gather":
         assert codes is not None and cand is not None
         return gather_scan(codes, q_packed, cand, plan.k, plan.d)
-    if plan.candidates.layout == "prebuilt":
-        assert layout is not None
-        dd, ii = _scan_select(layout.codes, q_packed, plan.k, plan)
-        return dd, layout_mod.to_original_ids(layout.perm, ii)
-    if plan.candidates.layout == "local_sort":
-        assert codes is not None
-        codes_l, perm = layout_mod.local_sort(codes, plan.d)
-        dd, ii = _scan_select(codes_l, q_packed, plan.k, plan)
-        return dd, layout_mod.to_original_ids(perm, ii)
+    if plan.candidates.layout in ("prebuilt", "local_sort"):
+        if plan.candidates.layout == "prebuilt":
+            assert layout is not None
+            codes_l, perm = layout.codes, layout.perm
+        else:
+            assert codes is not None
+            codes_l, perm = layout_mod.local_sort(codes, plan.d)
+        out = _scan_select(codes_l, q_packed, plan.k, plan,
+                           return_stats=return_stats)
+        with jax.named_scope("knn.layout.map_ids"):
+            ids = layout_mod.to_original_ids(perm, out[1])
+        return (out[0], ids, *out[2:])
     assert codes is not None
-    return _scan_select(codes, q_packed, plan.k, plan, id_offset=id_offset)
+    return _scan_select(codes, q_packed, plan.k, plan, id_offset=id_offset,
+                        return_stats=return_stats)
+
+
+def _check_stats(plan: QueryPlan) -> None:
+    """Tile counts come from the fused kernels: refuse a plan that does not
+    run them, or that runs them per shard under the concat merge."""
+    sel = plan.select.path
+    if sel not in ("fused", "fused_scan") or plan.candidates.kind == "gather":
+        raise ValueError(
+            f"return_stats needs the fused kernels; this plan resolved "
+            f"select={sel!r} over {plan.candidates.kind!r} candidates")
+    if (plan.merge.kind == "sharded"
+            and plan.merge.strategy not in HIST_STRATEGIES):
+        raise ValueError(
+            f"return_stats on a sharded plan needs a hist-family merge; "
+            f"this plan resolved merge={plan.merge.strategy!r}")
 
 
 # ---------------------------------------------------------------------------

@@ -183,6 +183,19 @@ def _finalize_slots(out_d: jax.Array, out_i: jax.Array, n_emit: jax.Array,
     return out_d, out_i
 
 
+def tile_stats(blocks_total: int, p1_run, p2_run) -> dict:
+    """The pruning telemetry of one two-pass call, from the tile counts the
+    kernel wrappers return (``return_tiles``): ``blocks_total`` (python
+    int, grid tiles per pass), ``p1_blocks_skipped`` (traced int32, tiles
+    pass 1 did not run: the enable mask excluded them) and
+    ``blocks_skipped`` (traced int32, tiles pass 2 did not run: disabled,
+    or whose block minimum exceeds every r* of their query block;
+    padding-only tiles included, they always prune)."""
+    return {"blocks_total": blocks_total,
+            "blocks_skipped": jnp.int32(blocks_total) - p2_run,
+            "p1_blocks_skipped": jnp.int32(blocks_total) - p1_run}
+
+
 def hamming_topk(q_packed: jax.Array, x_packed: jax.Array, k: int, bins: int,
                  n_valid: jax.Array | int | None = None,
                  block_mask: jax.Array | None = None,
@@ -211,12 +224,9 @@ def hamming_topk(q_packed: jax.Array, x_packed: jax.Array, k: int, bins: int,
     of core/layout.py. Queries whose candidate count falls below k get
     (bins, N) sentinels in the surplus slots, exactly like n_valid < k.
 
-    ``return_stats=True`` additionally returns a dict with the pruning
-    telemetry: ``blocks_total`` (python int, grid tiles per pass),
-    ``p1_blocks_skipped`` (traced int32, tiles the enable mask excluded
-    from pass 1), ``blocks_skipped`` (traced int32, tiles pass 2 pruned —
-    mask composed with the block-min guard; padding-only tiles included,
-    they always prune), and ``block_min`` (the summary itself).
+    ``return_stats=True`` additionally returns the ``tile_stats`` dict,
+    counted from the flags the two kernels were handed: pass 1's enable
+    rows and pass 2's run flags, summed inside their wrappers.
     """
     Q, N = q_packed.shape[0], x_packed.shape[0]
     k_k = min(k, N)
@@ -224,10 +234,7 @@ def hamming_topk(q_packed: jax.Array, x_packed: jax.Array, k: int, bins: int,
         out = (jnp.full((Q, k), bins, jnp.int32),
                jnp.full((Q, k), N, jnp.int32))
         if return_stats:
-            return out + ({"blocks_total": 0,
-                           "blocks_skipped": jnp.int32(0),
-                           "p1_blocks_skipped": jnp.int32(0),
-                           "block_min": jnp.zeros((0, 0), jnp.int32)},)
+            return out + (tile_stats(0, jnp.int32(0), jnp.int32(0)),)
         return out
     qp, xp, bq, bn, sub = _topk_blocked(q_packed, x_packed,
                                         max(bins, k_k), bq, bn, sub)
@@ -236,44 +243,36 @@ def hamming_topk(q_packed: jax.Array, x_packed: jax.Array, k: int, bins: int,
 
     # pass 1: the race -> per-query radius r*, the counts below it, and the
     # block-min summary pass 2 prunes with
-    hist, block_min = hamming_hist_pallas(qp, xp, bins, nv,
-                                          block_mask=block_mask,
-                                          bq=bq, bn=bn, sub=sub,
-                                          interpret=interp)
-    hist = hist[:Q]
-    cum = jnp.cumsum(hist, axis=-1)
-    # per-query candidate count: n_valid when unmasked, the enabled-row
-    # count under a block mask — k_eff must follow it or candidates with
-    # dist > 0 would be dropped whenever a query sees fewer than k rows
-    _, r_star, n_lt, n_emit = _radius_from_cum(cum, k_k)
+    with jax.named_scope("knn.pass1"):
+        p1 = hamming_hist_pallas(qp, xp, bins, nv, block_mask=block_mask,
+                                 bq=bq, bn=bn, sub=sub, interpret=interp,
+                                 return_tiles=return_stats)
+    hist, block_min = p1[0], p1[1]
+    with jax.named_scope("knn.radius"):
+        hist = hist[:Q]
+        cum = jnp.cumsum(hist, axis=-1)
+        # per-query candidate count: n_valid when unmasked, the enabled-row
+        # count under a block mask — k_eff must follow it or candidates
+        # with dist > 0 would be dropped whenever a query sees fewer than k
+        _, r_star, n_lt, n_emit = _radius_from_cum(cum, k_k)
+        # padded query rows get r*=-1 so they emit nothing
+        q_pad = qp.shape[0] - Q
+        r_p = jnp.pad(r_star, (0, q_pad), constant_values=-1)
+        nlt_p = jnp.pad(n_lt, (0, q_pad))
 
-    # pass 2: the reports — padded query rows get r*=-1 so they emit nothing
-    q_pad = qp.shape[0] - Q
-    r_p = jnp.pad(r_star, (0, q_pad), constant_values=-1)
-    nlt_p = jnp.pad(n_lt, (0, q_pad))
-    out_d, out_i = hamming_emit_pallas(qp, xp, r_p, nlt_p, bins, k_k, nv,
-                                       block_min=block_min,
-                                       block_mask=block_mask,
-                                       bq=bq, bn=bn, sub=sub,
-                                       interpret=interp)
-    out_d, out_i = out_d[:Q], out_i[:Q]
+    # pass 2: the reports
+    with jax.named_scope("knn.pass2"):
+        p2 = hamming_emit_pallas(qp, xp, r_p, nlt_p, bins, k_k, nv,
+                                 block_min=block_min, block_mask=block_mask,
+                                 bq=bq, bn=bn, sub=sub, interpret=interp,
+                                 return_tiles=return_stats)
 
     # untouched slots -> (bins, N) sentinels, then one O(k log k) sort per row
-    out_d, out_i = _finalize_slots(out_d, out_i, n_emit, k, k_k, bins, N)
+    with jax.named_scope("knn.finalize"):
+        out_d, out_i = _finalize_slots(p2[0][:Q], p2[1][:Q], n_emit, k, k_k,
+                                       bins, N)
     if return_stats:
-        # mirror the kernels' guards: pass 1 skips mask-disabled tiles;
-        # pass 2 skips a tile iff it is disabled OR its min valid distance
-        # exceeds every r* in its query block (disabled tiles summarize to
-        # bins, so the bound alone would already skip them — keep the
-        # explicit composition anyway, it is the contract)
-        enabled = (jnp.ones_like(block_min) if block_mask is None
-                   else block_mask.astype(jnp.int32)) != 0
-        max_r_b = jnp.max(r_p.reshape(-1, bq), axis=1)        # (Q_pad/bq,)
-        skipped = (~enabled) | (block_min > max_r_b[:, None])
-        return out_d, out_i, {"blocks_total": int(block_min.size),
-                              "blocks_skipped": jnp.sum(skipped),
-                              "p1_blocks_skipped": jnp.sum(~enabled),
-                              "block_min": block_min}
+        return out_d, out_i, tile_stats(int(block_min.size), p1[2], p2[2])
     return out_d, out_i
 
 
@@ -287,7 +286,7 @@ def hamming_topk_sharded(q_packed: jax.Array, x_local: jax.Array, k: int,
                          participate: jax.Array | None = None,
                          tree_fanout: int = 0,
                          bq: int | None = None, bn: int | None = None,
-                         sub: int | None = None):
+                         sub: int | None = None, return_stats: bool = False):
     """Distributed counting select — the sharded fused top-k WITHOUT a
     concat/sort merge. Call INSIDE ``shard_map``; collectives run over
     ``axis_names`` (``n_shards`` = product of their sizes).
@@ -343,14 +342,21 @@ def hamming_topk_sharded(q_packed: jax.Array, x_local: jax.Array, k: int,
     flat psum (strategy "hist_merge"); >= 2 switches both to the
     hierarchical ``_tree_psum`` schedule (strategy "hist_tree") —
     bit-identical results, tree-shaped traffic.
+
+    ``return_stats=True`` appends THIS shard's ``tile_stats`` (its own
+    kernels' tile counts; no collective): pass 2's tiles here are pruned
+    against the global r*.
     """
     axes = tuple(axis_names)
     Q, W = q_packed.shape
     n_loc = x_local.shape[0]
     k_k = min(k, n_shards * n_loc)
     if k_k == 0:
-        return (jnp.full((Q, k), bins, jnp.int32),
-                jnp.full((Q, k), 0, jnp.int32))
+        out = (jnp.full((Q, k), bins, jnp.int32),
+               jnp.full((Q, k), 0, jnp.int32))
+        if return_stats:
+            return out + (tile_stats(0, jnp.int32(0), jnp.int32(0)),)
+        return out
 
     # flat shard index over the collective axes (row-major, like the mesh)
     flat = jnp.zeros((), jnp.int32)
@@ -395,59 +401,75 @@ def hamming_topk_sharded(q_packed: jax.Array, x_local: jax.Array, k: int,
     interp = _interpret()
 
     # pass 1 locally, then merge the partial histograms: ONE global race
-    hist, block_min = hamming_hist_pallas(qp, xp, bins, nv,
-                                          block_mask=block_mask,
-                                          bq=bq, bn=bn, sub=sub,
-                                          interpret=interp)
+    with jax.named_scope("knn.pass1"):
+        p1 = hamming_hist_pallas(qp, xp, bins, nv, block_mask=block_mask,
+                                 bq=bq, bn=bn, sub=sub, interpret=interp,
+                                 return_tiles=return_stats)
+    hist, block_min = p1[0], p1[1]
     hist_loc = hist[:Q]
-    hist_glob = psum(hist_loc)
-    cum_g = jnp.cumsum(hist_glob, axis=-1)
-    gather = lambda c, i: jnp.take_along_axis(c, i[:, None], axis=-1)[:, 0]
-    _, r_star, n_lt, n_emit = _radius_from_cum(cum_g, k_k)
+    with jax.named_scope("knn.merge.hist"):
+        hist_glob = psum(hist_loc)
+    with jax.named_scope("knn.radius"):
+        cum_g = jnp.cumsum(hist_glob, axis=-1)
+        gather = lambda c, i: jnp.take_along_axis(c, i[:, None],
+                                                  axis=-1)[:, 0]
+        _, r_star, n_lt, n_emit = _radius_from_cum(cum_g, k_k)
 
-    # per-shard below-r*/tie counts from the LOCAL histogram; exclusive
-    # scan over the shard order = global-index-order slot bases
-    cum_l = jnp.cumsum(hist_loc, axis=-1)
-    l_lt = jnp.where(r_star > 0, gather(cum_l, jnp.maximum(r_star - 1, 0)), 0)
-    l_tie = gather(hist_loc, r_star)
-    counts = jnp.stack([l_lt, l_tie], axis=-1)                       # (Q, 2)
-    g_counts = jax.lax.all_gather(counts, axes, tiled=False)
-    g_counts = g_counts.reshape(n_shards, Q, 2)
-    before = (jnp.arange(n_shards, dtype=jnp.int32) < flat)[:, None]
-    base_lt = jnp.sum(jnp.where(before, g_counts[:, :, 0], 0), axis=0)
-    base_tie = n_lt + jnp.sum(jnp.where(before, g_counts[:, :, 1], 0), axis=0)
+        # per-shard below-r*/tie counts from the LOCAL histogram
+        cum_l = jnp.cumsum(hist_loc, axis=-1)
+        l_lt = jnp.where(r_star > 0,
+                         gather(cum_l, jnp.maximum(r_star - 1, 0)), 0)
+        l_tie = gather(hist_loc, r_star)
+        counts = jnp.stack([l_lt, l_tie], axis=-1)                   # (Q, 2)
+    # exclusive scan over the shard order = global-index-order slot bases
+    with jax.named_scope("knn.merge.bases"):
+        g_counts = jax.lax.all_gather(counts, axes, tiled=False)
+        g_counts = g_counts.reshape(n_shards, Q, 2)
+        before = (jnp.arange(n_shards, dtype=jnp.int32) < flat)[:, None]
+        base_lt = jnp.sum(jnp.where(before, g_counts[:, :, 0], 0), axis=0)
+        base_tie = n_lt + jnp.sum(jnp.where(before, g_counts[:, :, 1], 0),
+                                  axis=0)
 
     # pass 2 locally: this shard's winners scatter straight into its
     # disjoint global slots (padded query rows carry r* = -1: no emission)
-    q_pad = qp.shape[0] - Q
-    r_p = jnp.pad(r_star, (0, q_pad), constant_values=-1)
-    sb_p = jnp.pad(base_lt, (0, q_pad))
-    tb_p = jnp.pad(base_tie, (0, q_pad))
-    od, oi = hamming_emit_pallas(qp, xp, r_p, tb_p, bins, k_k, nv,
+    with jax.named_scope("knn.radius"):
+        q_pad = qp.shape[0] - Q
+        r_p = jnp.pad(r_star, (0, q_pad), constant_values=-1)
+        sb_p = jnp.pad(base_lt, (0, q_pad))
+        tb_p = jnp.pad(base_tie, (0, q_pad))
+    with jax.named_scope("knn.pass2"):
+        p2 = hamming_emit_pallas(qp, xp, r_p, tb_p, bins, k_k, nv,
                                  block_min=block_min, block_mask=block_mask,
                                  slot_base=sb_p,
                                  id_base=None if perm is not None else ib,
-                                 bq=bq, bn=bn, sub=sub, interpret=interp)
-    od, oi = od[:Q], oi[:Q]
+                                 bq=bq, bn=bn, sub=sub, interpret=interp,
+                                 return_tiles=return_stats)
+    od, oi = p2[0][:Q], p2[1][:Q]
     if perm is not None:
         # winners were emitted as layout positions: map them back to local
         # ids on the slots THIS shard owns, zero elsewhere, so the psum
         # below still assembles disjoint ranges
-        iota = jnp.arange(k_k, dtype=jnp.int32)[None, :]
-        owned = (((iota >= base_lt[:, None])
-                  & (iota < (base_lt + l_lt)[:, None]))
-                 | ((iota >= base_tie[:, None])
-                    & (iota < (base_tie + l_tie)[:, None])))
-        perm = jnp.asarray(perm, jnp.int32)
-        mapped = perm[jnp.minimum(oi, n_loc - 1)] + ib
-        oi = jnp.where(owned, mapped, 0)
-        od = jnp.where(owned, od, 0)
+        with jax.named_scope("knn.layout.map_ids"):
+            iota = jnp.arange(k_k, dtype=jnp.int32)[None, :]
+            owned = (((iota >= base_lt[:, None])
+                      & (iota < (base_lt + l_lt)[:, None]))
+                     | ((iota >= base_tie[:, None])
+                        & (iota < (base_tie + l_tie)[:, None])))
+            perm = jnp.asarray(perm, jnp.int32)
+            mapped = perm[jnp.minimum(oi, n_loc - 1)] + ib
+            oi = jnp.where(owned, mapped, 0)
+            od = jnp.where(owned, od, 0)
 
-    od = psum(od)
-    oi = psum(oi)
+    with jax.named_scope("knn.merge.out"):
+        od = psum(od)
+        oi = psum(oi)
 
     # untouched slots -> (bins, n_total) sentinels, one O(k log k) sort
-    return _finalize_slots(od, oi, n_emit, k, k_k, bins, nt)
+    with jax.named_scope("knn.finalize"):
+        out = _finalize_slots(od, oi, n_emit, k, k_k, bins, nt)
+    if return_stats:
+        return out + (tile_stats(int(block_min.size), p1[2], p2[2]),)
+    return out
 
 
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
@@ -469,5 +491,5 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
 
 
 __all__ = ["flash_attention", "hamming_distance", "hamming_hist",
-           "hamming_topk", "hamming_topk_sharded", "ref", "topk_geometry",
-           "tuning"]
+           "hamming_topk", "hamming_topk_sharded", "ref", "tile_stats",
+           "topk_geometry", "tuning"]

@@ -213,14 +213,14 @@ def _row_spec(nj: int):
 
 
 @functools.partial(jax.jit, static_argnames=("bins", "bq", "bn", "sub",
-                                             "interpret"))
+                                             "interpret", "return_tiles"))
 def hamming_hist_pallas(q_packed: jax.Array, x_packed: jax.Array, bins: int,
                         n_valid: jax.Array | None = None,
                         block_mask: jax.Array | None = None,
                         bq: int = 64, bn: int = 1024, sub: int = 64,
-                        interpret: bool = False):
+                        interpret: bool = False, return_tiles: bool = False):
     """q: (Q, W), x: (N, W) -> (hist (Q, bins) int32,
-    block_min (Q/bq, N/bn) int32).
+    block_min (Q/bq, N/bn) int32[, tiles_run int32]).
 
     ``hist`` is the per-query distance histogram; ``block_min`` is the
     minimum valid distance within each (query-block, data-block) grid tile
@@ -229,7 +229,9 @@ def hamming_hist_pallas(q_packed: jax.Array, x_packed: jax.Array, bins: int,
     exactly from both outputs. ``block_mask``: (Q/bq, N/bn) int32 enable
     mask (None = all tiles enabled); a zero tile is skipped outright — its
     rows are outside the candidate set, so they are excluded from the
-    histogram and its summary entry is bins."""
+    histogram and its summary entry is bins. ``return_tiles=True`` also
+    returns how many grid tiles the kernel ran: the sum of the enable rows
+    it was handed."""
     Q, W = q_packed.shape
     N, _ = x_packed.shape
     bq, bn = min(bq, Q), min(bn, N)
@@ -262,6 +264,9 @@ def hamming_hist_pallas(q_packed: jax.Array, x_packed: jax.Array, bins: int,
         scratch_shapes=[pltpu.VMEM((sub, bq), jnp.int32)],
         interpret=interpret,
     )(nv, en, *_codes(q_packed, x_packed))
+    if return_tiles:
+        return (_unblock(hist8), bmin.reshape(nq, nj),
+                jnp.sum(en != 0, dtype=jnp.int32))
     return _unblock(hist8), bmin.reshape(nq, nj)
 
 
@@ -346,7 +351,7 @@ def _emit_kernel(nv_ref, ib_ref, run_ref, q_ref, x_ref, r_ref, nlt_ref,
 
 
 @functools.partial(jax.jit, static_argnames=("bins", "k", "bq", "bn", "sub",
-                                             "interpret"))
+                                             "interpret", "return_tiles"))
 def hamming_emit_pallas(q_packed: jax.Array, x_packed: jax.Array,
                         r_star: jax.Array, n_lt: jax.Array, bins: int, k: int,
                         n_valid: jax.Array | None = None,
@@ -355,7 +360,7 @@ def hamming_emit_pallas(q_packed: jax.Array, x_packed: jax.Array,
                         slot_base: jax.Array | None = None,
                         id_base: jax.Array | None = None,
                         bq: int = 64, bn: int = 1024, sub: int = 64,
-                        interpret: bool = False):
+                        interpret: bool = False, return_tiles: bool = False):
     """Emit the top-k winners given the pass-1 radius.
 
     q: (Q, W), x: (N, W); r_star/n_lt: (Q,) int32 — per-query k-th-smallest
@@ -379,7 +384,9 @@ def hamming_emit_pallas(q_packed: jax.Array, x_packed: jax.Array,
     Returns (dists (Q, k), ids (Q, k)) int32, slot-ordered (NOT distance
     sorted): slots [0, n_lt) hold dist < r* rows in index order, subsequent
     slots hold r*-ties in index order; untouched slots are 0 — the caller
-    masks slots >= n_emitted and sorts (kernels/ops.py::hamming_topk)."""
+    masks slots >= n_emitted and sorts (kernels/ops.py::hamming_topk).
+    ``return_tiles=True`` appends how many grid tiles ran: the sum of the
+    run flags the kernel was handed."""
     Q, W = q_packed.shape
     N, _ = x_packed.shape
     bq, bn = min(bq, Q), min(bn, N)
@@ -424,4 +431,6 @@ def hamming_emit_pallas(q_packed: jax.Array, x_packed: jax.Array,
         interpret=interpret,
     )(nv, ib, _tile_rows(run, nq, nj), *_codes(q_packed, x_packed), r1,
       _as_i32(n_lt).reshape(nq, 1, bq), sb1)
+    if return_tiles:
+        return _unblock(od8), _unblock(oi8), jnp.sum(run, dtype=jnp.int32)
     return _unblock(od8), _unblock(oi8)

@@ -3,13 +3,20 @@ attached: the fused top-k passes at the TPU tiling the heuristic picks for
 the kNN-TagSpace shape (d=256, Q=4096) at N = 2^20 and N = 2^26, and the
 materializing distance kernel. Each compiled program must hold the Mosaic
 kernel (``tpu_custom_call``) — what the TPU compiler refuses fails here,
-without a chip. All compiles stay in this one file (one worker, one TPU
-compiler library)."""
+without a chip. So must the benchmark's two search programs at small N (one
+chip over a prebuilt layout, four chips through hist_merge), under the
+kernels' own instruction names, with no host callback. All compiles stay in
+this one file (one worker, one TPU compiler library)."""
+import re
+
+import numpy as np
 import pytest
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from repro.core import engine, layout as layout_mod
 from repro.kernels import tuning
 from repro.kernels.hamming import hamming_distance_pallas
 from repro.kernels.topk_select import hamming_emit_pallas, hamming_hist_pallas
@@ -87,3 +94,48 @@ def test_distance_kernel_compiles_for_v5e(spec):
     compiled = fn.lower(spec((Q, W), jnp.uint32),
                         spec((n, W), jnp.uint32)).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+SEARCH_Q, SEARCH_ROWS = 128, 1 << 20
+_INSTR = re.compile(r"^\s*(?:ROOT )?%([\w.-]+) = ", re.M)
+
+
+@pytest.fixture
+def as_v5e(monkeypatch):
+    """The program asks ``jax.default_backend()`` for its kernel geometry and
+    whether to interpret the kernels: answer for the described chip."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+
+def _check_search_program(hlo: str, collectives=()):
+    """The benchmark's device-trace readers key on these instruction names;
+    a host callback would keep the program out of the compile cache."""
+    names = _INSTR.findall(hlo)
+    for prefix in ("hamming_hist_pallas", "hamming_emit_pallas") + collectives:
+        assert any(n.startswith(prefix) for n in names), prefix
+    assert not re.search(r'custom_call_target="[^"]*callback', hlo)
+    assert "is_host_transfer=true" not in hlo
+
+
+def test_one_chip_layout_search_compiles_for_v5e(spec, as_v5e):
+    n_buckets = 1 << layout_mod.default_bits(SEARCH_ROWS)
+    lay = layout_mod.BucketLayout(
+        codes=spec((SEARCH_ROWS, W), jnp.uint32), perm=spec((SEARCH_ROWS,)),
+        inv=spec((SEARCH_ROWS,)), starts=spec((n_buckets + 1,)))
+    fn = jax.jit(lambda cc, lo, q: engine.KNNEngine(
+        codes=cc, d=D, layout=lo).search(q, K))
+    compiled = fn.lower(spec((SEARCH_ROWS, W), jnp.uint32), lay,
+                        spec((SEARCH_Q, W), jnp.uint32)).compile()
+    _check_search_program(compiled.as_text())
+
+
+def test_four_chip_hist_merge_search_compiles_for_v5e(topo, as_v5e):
+    mesh = Mesh(np.asarray(topo.devices), ("data",))
+    fn = jax.jit(lambda cc, q: engine.search_sharded(
+        cc, q, K, D, mesh, ("data",)))
+    compiled = fn.lower(
+        jax.ShapeDtypeStruct((4 * SEARCH_ROWS, W), jnp.uint32,
+                             sharding=NamedSharding(mesh, P("data", None))),
+        jax.ShapeDtypeStruct((SEARCH_Q, W), jnp.uint32,
+                             sharding=NamedSharding(mesh, P()))).compile()
+    _check_search_program(compiled.as_text(), collectives=("all-reduce",))
