@@ -72,7 +72,7 @@ def run(report):
         # shrink the query batch on the large set to bound wall time, and
         # re-time the materialized-XOR path at the same batch so
         # speedup_vs_xor is an apples-to-apples pair. The single-shot path
-        # (select="fused": one hist + one emit pallas_call over all of N)
+        # (select="fused": pass 1 and one emit pallas_call over all of N)
         # and the chunk-scanned variant (select="fused_scan": lax.scan +
         # O(k) merge per chunk) are timed as a PAIR at a chunk that forces
         # several scan steps, so speedup_vs_scan isolates the scan

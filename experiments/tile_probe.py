@@ -150,6 +150,7 @@ def main(argv=None) -> int:
         row = {"batch": b, "blocks_total": int(st["blocks_total"]),
                "blocks_skipped": int(st["blocks_skipped"]),
                "p1_blocks_skipped": int(st["p1_blocks_skipped"]),
+               "p1_fine_blocks_skipped": int(st["p1_fine_blocks_skipped"]),
                "same_answers": same, "plain_s": secs[False][-1],
                "stats_s": secs[True][-1]}
         if "shard_blocks_skipped" in st:

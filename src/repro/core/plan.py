@@ -931,7 +931,8 @@ def _scan_select(codes_packed: jax.Array, q_packed: jax.Array, k: int,
             if return_stats:
                 chunk_tiles.append(out[2]["blocks_total"])
                 return (best_d, best_i), (out[2]["p1_blocks_skipped"],
-                                          out[2]["blocks_skipped"])
+                                          out[2]["blocks_skipped"],
+                                          out[2]["p1_fine_blocks_skipped"])
             return (best_d, best_i), None
     else:
         select_fn = {"composite": topk.composite_topk,
@@ -959,7 +960,8 @@ def _scan_select(codes_packed: jax.Array, q_packed: jax.Array, k: int,
         return bd, bi + id_offset, {
             "blocks_total": n_chunks * chunk_tiles[0],
             "blocks_skipped": jnp.sum(skipped[1]),
-            "p1_blocks_skipped": jnp.sum(skipped[0])}
+            "p1_blocks_skipped": jnp.sum(skipped[0]),
+            "p1_fine_blocks_skipped": jnp.sum(skipped[2])}
     return bd, bi + id_offset
 
 
@@ -977,6 +979,11 @@ def gather_scan(codes: jax.Array, q_packed: jax.Array, cand: jax.Array,
     ids = jnp.take_along_axis(cand, jnp.minimum(ii, cand.shape[1] - 1), axis=-1)
     ids = jnp.where(dd > d, -1, ids)
     return dd, ids
+
+
+# the per-shard tile counts a sharded fused select hands out of shard_map
+_SHARD_COUNTS = ("p1_blocks_skipped", "blocks_skipped",
+                 "p1_fine_blocks_skipped")
 
 
 def _execute_sharded(plan: QueryPlan, q_packed: jax.Array, codes: jax.Array,
@@ -1007,8 +1014,9 @@ def _execute_sharded(plan: QueryPlan, q_packed: jax.Array, codes: jax.Array,
 
     ``return_stats`` (fused select, hist-family merge): each shard's tile
     counts leave ``shard_map`` as one (n_shards,) array per count
-    (``shard_blocks_skipped``, ``shard_p1_blocks_skipped``: no
-    collective), beside their sums under the ``tile_stats`` keys;
+    (``shard_blocks_skipped``, ``shard_p1_blocks_skipped``,
+    ``shard_p1_fine_blocks_skipped``: no collective), beside their sums
+    under the ``tile_stats`` keys;
     ``shard_blocks_total`` is one shard's grid."""
     axes = plan.merge.axes
     k, k_local = plan.k, plan.merge.k_local
@@ -1087,8 +1095,8 @@ def _execute_sharded(plan: QueryPlan, q_packed: jax.Array, codes: jax.Array,
                 return_stats=return_stats)
             if return_stats:
                 shard_tiles.append(out[2]["blocks_total"])
-                return out[:2] + (out[2]["p1_blocks_skipped"].reshape(1),
-                                  out[2]["blocks_skipped"].reshape(1))
+                return out[:2] + tuple(
+                    out[2][key].reshape(1) for key in _SHARD_COUNTS)
             return out
         if nv is not None:
             # uneven shards on the legacy merge: mask padding in-kernel,
@@ -1140,20 +1148,18 @@ def _execute_sharded(plan: QueryPlan, q_packed: jax.Array, codes: jax.Array,
     shard_tiles = []            # one shard's grid (static)
     out_specs = (P(None, None), P(None, None))
     if return_stats:
-        out_specs += (P(axes), P(axes))
+        out_specs += (P(axes),) * len(_SHARD_COUNTS)
     mapped = shard_map(local, mesh=mesh,
                        in_specs=(P(axes, None), P(None, None)),
                        out_specs=out_specs)
     out = mapped(codes, q_packed)
     if return_stats:
-        p1, p2 = out[2], out[3]
-        return out[0], out[1], {
-            "blocks_total": n_dev * shard_tiles[0],
-            "blocks_skipped": jnp.sum(p2),
-            "p1_blocks_skipped": jnp.sum(p1),
-            "shard_blocks_total": shard_tiles[0],
-            "shard_blocks_skipped": p2,
-            "shard_p1_blocks_skipped": p1}
+        stats = {"blocks_total": n_dev * shard_tiles[0],
+                 "shard_blocks_total": shard_tiles[0]}
+        for key, per_shard in zip(_SHARD_COUNTS, out[2:]):
+            stats[key] = jnp.sum(per_shard)
+            stats["shard_" + key] = per_shard
+        return out[0], out[1], stats
     return out
 
 

@@ -7,18 +7,21 @@ the kernels stay assert-simple; padded dataset rows are masked exactly
 inside the kernels by the ``n_valid`` scalar. Block shapes come from the
 shared heuristic in kernels/tuning.py unless explicitly overridden.
 
-``hamming_topk`` is the engine's single-shot fused select: one hist + one
+``hamming_topk`` is the engine's single-shot fused select: pass 1 (one
+hist ``pallas_call``, or two for the two-level race of ``_race``) and one
 emit ``pallas_call`` over the WHOLE datastore for any N, with the pass-1
 block-min summary pruning pass-2 tiles that cannot hold a winner.
 
 ``hamming_topk_sharded`` is the same two-pass select distributed across a
 device mesh (call it INSIDE ``shard_map``): the paper's counters are
-additive partial histograms, so one ``psum`` of the tiny (Q, bins) counts
+additive partial histograms, so a ``psum`` of the tiny per-level counts
 yields ONE global per-query radius r*, and each shard then emits its
 winners into disjoint slots of the global (Q, k) output — no per-shard
 top-k materialization, no host concat/sort merge.
 """
 from __future__ import annotations
+
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -108,17 +111,106 @@ def hamming_hist(q_packed: jax.Array, x_packed: jax.Array, bins: int,
     return hist[:Q]
 
 
-def _radius_from_cum(cum: jax.Array, k_k: int):
+def _gather(c: jax.Array, i: jax.Array) -> jax.Array:
+    return jnp.take_along_axis(c, i[:, None], axis=-1)[:, 0]
+
+
+def _radius_from_cum(cum: jax.Array, k_k, below=0):
     """The counting select's "finish line": from a cumulative histogram,
     the per-query effective k, k-th-smallest radius r*, strict-below count
     and emit count. ONE definition — the single-device and distributed
-    selects must derive the radius identically or they diverge."""
+    selects, and both levels of the two-level race (``_race``), must derive
+    the radius identically or they diverge.
+
+    ``cum`` may cover a window of consecutive distances instead of all of
+    them: it then counts from ``below``, the candidates under the window,
+    r* comes back as an offset into the window, and ``k_k`` is the race's
+    own (Q,) k_eff, which the window's last count already reaches."""
     k_eff = jnp.minimum(k_k, cum[:, -1])                             # (Q,)
     r_star = jnp.argmax(cum >= k_eff[:, None], axis=-1).astype(jnp.int32)
-    gather = lambda c, i: jnp.take_along_axis(c, i[:, None], axis=-1)[:, 0]
-    n_lt = jnp.where(r_star > 0, gather(cum, jnp.maximum(r_star - 1, 0)), 0)
-    n_emit = jnp.minimum(gather(cum, r_star), k_eff)
+    n_lt = jnp.where(r_star > 0, _gather(cum, jnp.maximum(r_star - 1, 0)),
+                     below)
+    n_emit = jnp.minimum(_gather(cum, r_star), k_eff)
     return k_eff, r_star, n_lt, n_emit
+
+
+def _split_at(hist: jax.Array, r: jax.Array, below=0):
+    """A histogram's counts strictly below and at per-query lane ``r``,
+    counting from ``below`` (the candidates under its first lane)."""
+    cum = jnp.cumsum(hist, axis=-1) + jnp.expand_dims(below, -1)
+    lt = jnp.where(r > 0, _gather(cum, jnp.maximum(r - 1, 0)), below)
+    return lt, _gather(hist, r)
+
+
+class _Race(NamedTuple):
+    """What pass 1 hands pass 2: per query the race's r*, strict-below
+    count n_lt and emit count n_emit (global, after ``merge``), this
+    device's own counts below and at r* (the sharded select's slot bases),
+    the block-min summary, and the tiles each pass-1 call ran (the fine
+    call's None on a one-level race; ``return_tiles`` only)."""
+    r_star: jax.Array
+    n_lt: jax.Array
+    n_emit: jax.Array
+    lt: jax.Array
+    tie: jax.Array
+    block_min: jax.Array
+    tiles: jax.Array | None
+    fine_tiles: jax.Array | None
+
+
+def _race(hist_call, Q: int, k_k: int, bins: int, shift: int,
+          merge=None) -> _Race:
+    """Pass 1 and the radius, for the single-device and sharded selects.
+
+    ``hist_call(**kw)`` runs ``hamming_hist_pallas`` over this device's
+    padded rows and query batch with one level's keywords; ``merge`` sums
+    partial histograms across devices (None: one device). ``shift`` is
+    ``tuning.race_shift(bins)``:
+
+    * 0 — one level: the (Q, bins) histogram gives r* directly.
+    * s > 0 — two levels, exact and never a full histogram: the coarse
+      call counts ``dist >> s`` (ceil(bins / 2^s) lanes) and emits the
+      block-min summary; its finish line is the coarse bucket c* holding
+      r*, and the count below c* is exact. The fine call counts the 2^s
+      distances of that bucket, from base = c* << s, skipping tiles whose
+      block minimum lies above every window of their query block; the
+      finish line over the window, counted from the coarse count below
+      it, is r*. r*, n_lt and n_emit equal the one-level race's."""
+    merge = merge or (lambda h: h)
+    with jax.named_scope("knn.pass1"):
+        p1 = hist_call(shift=shift)
+    hist, block_min = p1[0][:Q], p1[1]
+    q_pad = p1[0].shape[0] - Q
+    tiles = p1[2] if len(p1) > 2 else None
+    with jax.named_scope("knn.merge.hist"):
+        glob = merge(hist)
+    with jax.named_scope("knn.radius"):
+        # per-query candidate count: n_valid when unmasked, the enabled-row
+        # count under a block mask — k_eff must follow it or candidates
+        # with dist > 0 would be dropped whenever a query sees fewer than k
+        k_eff, r_star, n_lt, n_emit = _radius_from_cum(
+            jnp.cumsum(glob, axis=-1), k_k)
+        lt, tie = _split_at(hist, r_star)
+    if not shift:
+        return _Race(r_star, n_lt, n_emit, lt, tie, block_min, tiles, None)
+
+    width = 1 << shift
+    with jax.named_scope("knn.radius"):
+        base = r_star << shift
+        # padded query rows get a window below every distance: they count
+        # nothing and never widen their block's pruning bound
+        base_p = jnp.pad(base, (0, q_pad), constant_values=-width)
+    with jax.named_scope("knn.pass1"):
+        p1 = hist_call(base=base_p, block_min=block_min, window=width)
+    fine = p1[0][:Q]
+    with jax.named_scope("knn.merge.hist"):
+        glob = merge(fine)
+    with jax.named_scope("knn.radius"):
+        _, f, n_lt, n_emit = _radius_from_cum(
+            n_lt[:, None] + jnp.cumsum(glob, axis=-1), k_eff, n_lt)
+        lt, tie = _split_at(fine, f, lt)
+    return _Race(base + f, n_lt, n_emit, lt, tie, block_min, tiles,
+                p1[2] if len(p1) > 2 else None)
 
 
 def _tree_psum(x: jax.Array, axes, fanout: int) -> jax.Array:
@@ -183,17 +275,23 @@ def _finalize_slots(out_d: jax.Array, out_i: jax.Array, n_emit: jax.Array,
     return out_d, out_i
 
 
-def tile_stats(blocks_total: int, p1_run, p2_run) -> dict:
+def tile_stats(blocks_total: int, p1_run, p2_run, fine_run=None) -> dict:
     """The pruning telemetry of one two-pass call, from the tile counts the
     kernel wrappers return (``return_tiles``): ``blocks_total`` (python
     int, grid tiles per pass), ``p1_blocks_skipped`` (traced int32, tiles
-    pass 1 did not run: the enable mask excluded them) and
-    ``blocks_skipped`` (traced int32, tiles pass 2 did not run: disabled,
-    or whose block minimum exceeds every r* of their query block;
-    padding-only tiles included, they always prune)."""
+    pass 1 did not run: the enable mask excluded them),
+    ``p1_fine_blocks_skipped`` (traced int32, tiles the fine level of a
+    two-level race did not run: disabled, or whose block minimum lies above
+    every window of their query block; 0 on a one-level race, which has no
+    fine level) and ``blocks_skipped`` (traced int32, tiles pass 2 did not
+    run: disabled, or whose block minimum exceeds every r* of their query
+    block; padding-only tiles included, they always prune)."""
+    total = jnp.int32(blocks_total)
     return {"blocks_total": blocks_total,
-            "blocks_skipped": jnp.int32(blocks_total) - p2_run,
-            "p1_blocks_skipped": jnp.int32(blocks_total) - p1_run}
+            "blocks_skipped": total - p2_run,
+            "p1_blocks_skipped": total - p1_run,
+            "p1_fine_blocks_skipped": (jnp.int32(0) if fine_run is None
+                                       else total - fine_run)}
 
 
 def hamming_topk(q_packed: jax.Array, x_packed: jax.Array, k: int, bins: int,
@@ -204,18 +302,21 @@ def hamming_topk(q_packed: jax.Array, x_packed: jax.Array, k: int, bins: int,
     """Single-shot fused two-pass top-k over the WHOLE datastore:
     (Q, W) x (N, W) -> (dists (Q, k), ids (Q, k)).
 
-    The engine's high-throughput select, one hist + one emit ``pallas_call``
-    for any N (the Pallas grid streams the N dimension; arbitrary N is
-    padded to a block multiple here and masked exactly in-kernel): pass 1
-    histograms distances into [0, bins) (clamped at bins-1; pass bins > max
-    distance for exactness) and emits the (Q/bq, N/bn) block-min pruning
-    summary, pass 2 re-streams the codes and emits the winners, skipping
-    every (query-block, data-block) tile whose summary proves it holds no
-    winner. Only (Q, bins), the tiny summary, and (Q, k) ever leave the
-    kernels — the (Q, N) distance matrix is never materialized. Semantics
-    match ``topk.counting_topk`` on the clamped distances: ascending, ties
-    broken by index order, rows beyond min(k, n_valid) padded with
-    (bins, N). Rows with global id >= n_valid are excluded exactly.
+    The engine's high-throughput select, pass 1 and one emit
+    ``pallas_call`` for any N (the Pallas grid streams the N dimension;
+    arbitrary N is padded to a block multiple here and masked exactly
+    in-kernel): pass 1 races distances in [0, bins) (clamped at bins-1;
+    pass bins > max distance for exactness) to the radius r* — one
+    histogram call, or at large ``bins`` a coarse and a fine one
+    (``_race``, ``tuning.race_shift``) — and emits the (Q/bq, N/bn)
+    block-min pruning summary, pass 2 re-streams the codes and emits the
+    winners, skipping every (query-block, data-block) tile whose summary
+    proves it holds no winner. Only (Q, bins) counts or fewer, the tiny
+    summary, and (Q, k) ever leave the kernels — the (Q, N) distance
+    matrix is never materialized. Semantics match ``topk.counting_topk``
+    on the clamped distances: ascending, ties broken by index order, rows
+    beyond min(k, n_valid) padded with (bins, N). Rows with global id >=
+    n_valid are excluded exactly.
 
     ``block_mask``: optional (q_pad//bq, n_pad//bn) int32 enable mask over
     the grid tiles (geometry from ``topk_geometry``): a zero tile is
@@ -243,36 +344,31 @@ def hamming_topk(q_packed: jax.Array, x_packed: jax.Array, k: int, bins: int,
 
     # pass 1: the race -> per-query radius r*, the counts below it, and the
     # block-min summary pass 2 prunes with
-    with jax.named_scope("knn.pass1"):
-        p1 = hamming_hist_pallas(qp, xp, bins, nv, block_mask=block_mask,
-                                 bq=bq, bn=bn, sub=sub, interpret=interp,
-                                 return_tiles=return_stats)
-    hist, block_min = p1[0], p1[1]
+    race = _race(lambda **kw: hamming_hist_pallas(
+        qp, xp, bins, nv, block_mask=block_mask, bq=bq, bn=bn, sub=sub,
+        interpret=interp, return_tiles=return_stats, **kw),
+        Q, k_k, bins, tuning.race_shift(bins))
     with jax.named_scope("knn.radius"):
-        hist = hist[:Q]
-        cum = jnp.cumsum(hist, axis=-1)
-        # per-query candidate count: n_valid when unmasked, the enabled-row
-        # count under a block mask — k_eff must follow it or candidates
-        # with dist > 0 would be dropped whenever a query sees fewer than k
-        _, r_star, n_lt, n_emit = _radius_from_cum(cum, k_k)
         # padded query rows get r*=-1 so they emit nothing
         q_pad = qp.shape[0] - Q
-        r_p = jnp.pad(r_star, (0, q_pad), constant_values=-1)
-        nlt_p = jnp.pad(n_lt, (0, q_pad))
+        r_p = jnp.pad(race.r_star, (0, q_pad), constant_values=-1)
+        nlt_p = jnp.pad(race.n_lt, (0, q_pad))
 
     # pass 2: the reports
     with jax.named_scope("knn.pass2"):
         p2 = hamming_emit_pallas(qp, xp, r_p, nlt_p, bins, k_k, nv,
-                                 block_min=block_min, block_mask=block_mask,
-                                 bq=bq, bn=bn, sub=sub, interpret=interp,
+                                 block_min=race.block_min,
+                                 block_mask=block_mask, bq=bq, bn=bn,
+                                 sub=sub, interpret=interp,
                                  return_tiles=return_stats)
 
     # untouched slots -> (bins, N) sentinels, then one O(k log k) sort per row
     with jax.named_scope("knn.finalize"):
-        out_d, out_i = _finalize_slots(p2[0][:Q], p2[1][:Q], n_emit, k, k_k,
-                                       bins, N)
+        out_d, out_i = _finalize_slots(p2[0][:Q], p2[1][:Q], race.n_emit, k,
+                                       k_k, bins, N)
     if return_stats:
-        return out_d, out_i, tile_stats(int(block_min.size), p1[2], p2[2])
+        return out_d, out_i, tile_stats(int(race.block_min.size),
+                                        race.tiles, p2[2], race.fine_tiles)
     return out_d, out_i
 
 
@@ -298,12 +394,15 @@ def hamming_topk_sharded(q_packed: jax.Array, x_local: jax.Array, k: int,
     cut are picked in layout-position order — the same report-order
     freedom every layout-streaming path has, core/layout.py):
 
-    1. each shard runs pass 1 over its slice — its (Q, bins) histogram is
-       a PARTIAL histogram of the global race (counters are additive);
-    2. one ``psum`` merges them; the global r*, below-count n_lt and
-       emit count derive exactly as in the single-device select;
+    1. each shard runs pass 1 over its slice — its histogram is a
+       PARTIAL histogram of the global race (counters are additive);
+    2. a ``psum`` merges them; the global r*, below-count n_lt and emit
+       count derive exactly as in the single-device select (``_race``: on
+       a two-level race the coarse histograms merge into one global
+       window base, each shard counts that window, and a second psum of
+       the (Q, 2^s) fine counts gives r*);
     3. each shard derives its own below-r*/tie counts from its LOCAL
-       histogram; one tiny (Q, 2)-per-shard all-gather turns them into
+       histograms; one tiny (Q, 2)-per-shard all-gather turns them into
        exclusive-scan slot bases, so every shard owns a disjoint slice of
        the global (Q, k) slot space (without ``perm``, ids stay in global
        index order — shard slices are contiguous id ranges — so tie
@@ -314,8 +413,9 @@ def hamming_topk_sharded(q_packed: jax.Array, x_local: jax.Array, k: int,
        mask compose as usual) with ``slot_base``/``id_base`` from step 3,
        and a final ``psum`` assembles the disjoint slots.
 
-    Cross-device traffic is O(Q·bins) histogram counts + O(Q·n_shards)
-    base counts + the O(Q·k) output — never O(n_shards·Q·k) candidates.
+    Cross-device traffic is O(Q·bins) histogram counts (fewer on a
+    two-level race) + O(Q·n_shards) base counts + the O(Q·k) output —
+    never O(n_shards·Q·k) candidates.
 
     ``n_valid``: this shard's valid-row count (rows beyond it are padding;
     uneven shards pad to a common n_loc). ``id_base``/``n_total``: this
@@ -400,26 +500,14 @@ def hamming_topk_sharded(q_packed: jax.Array, x_local: jax.Array, k: int,
                                         max(bins, k_k), bq, bn, sub)
     interp = _interpret()
 
-    # pass 1 locally, then merge the partial histograms: ONE global race
-    with jax.named_scope("knn.pass1"):
-        p1 = hamming_hist_pallas(qp, xp, bins, nv, block_mask=block_mask,
-                                 bq=bq, bn=bn, sub=sub, interpret=interp,
-                                 return_tiles=return_stats)
-    hist, block_min = p1[0], p1[1]
-    hist_loc = hist[:Q]
-    with jax.named_scope("knn.merge.hist"):
-        hist_glob = psum(hist_loc)
+    # pass 1 locally, then merge the partial histograms: ONE global race;
+    # this shard's below-r*/tie counts come from its LOCAL histograms
+    race = _race(lambda **kw: hamming_hist_pallas(
+        qp, xp, bins, nv, block_mask=block_mask, bq=bq, bn=bn, sub=sub,
+        interpret=interp, return_tiles=return_stats, **kw),
+        Q, k_k, bins, tuning.race_shift(bins), merge=psum)
+    r_star, n_lt, l_lt, l_tie = race.r_star, race.n_lt, race.lt, race.tie
     with jax.named_scope("knn.radius"):
-        cum_g = jnp.cumsum(hist_glob, axis=-1)
-        gather = lambda c, i: jnp.take_along_axis(c, i[:, None],
-                                                  axis=-1)[:, 0]
-        _, r_star, n_lt, n_emit = _radius_from_cum(cum_g, k_k)
-
-        # per-shard below-r*/tie counts from the LOCAL histogram
-        cum_l = jnp.cumsum(hist_loc, axis=-1)
-        l_lt = jnp.where(r_star > 0,
-                         gather(cum_l, jnp.maximum(r_star - 1, 0)), 0)
-        l_tie = gather(hist_loc, r_star)
         counts = jnp.stack([l_lt, l_tie], axis=-1)                   # (Q, 2)
     # exclusive scan over the shard order = global-index-order slot bases
     with jax.named_scope("knn.merge.bases"):
@@ -439,7 +527,8 @@ def hamming_topk_sharded(q_packed: jax.Array, x_local: jax.Array, k: int,
         tb_p = jnp.pad(base_tie, (0, q_pad))
     with jax.named_scope("knn.pass2"):
         p2 = hamming_emit_pallas(qp, xp, r_p, tb_p, bins, k_k, nv,
-                                 block_min=block_min, block_mask=block_mask,
+                                 block_min=race.block_min,
+                                 block_mask=block_mask,
                                  slot_base=sb_p,
                                  id_base=None if perm is not None else ib,
                                  bq=bq, bn=bn, sub=sub, interpret=interp,
@@ -466,9 +555,10 @@ def hamming_topk_sharded(q_packed: jax.Array, x_local: jax.Array, k: int,
 
     # untouched slots -> (bins, n_total) sentinels, one O(k log k) sort
     with jax.named_scope("knn.finalize"):
-        out = _finalize_slots(od, oi, n_emit, k, k_k, bins, nt)
+        out = _finalize_slots(od, oi, race.n_emit, k, k_k, bins, nt)
     if return_stats:
-        return out + (tile_stats(int(block_min.size), p1[2], p2[2]),)
+        return out + (tile_stats(int(race.block_min.size), race.tiles,
+                                 p2[2], race.fine_tiles),)
     return out
 
 

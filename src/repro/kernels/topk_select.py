@@ -9,7 +9,11 @@ are that pipeline on TPU — the (Q, N) distance matrix never exists in HBM:
   HBM->VMEM, XOR+popcount against the query tile, and accumulate a
   per-query distance histogram. Only (Q, bins) counts leave the kernel —
   the same reduction the AP performs by keeping counters next to the
-  Hamming macros.
+  Hamming macros. At large ``bins`` the race runs in two levels
+  (kernels/ops.py::_race): the same kernel counts ``dist >> s`` into
+  ceil(bins / 2^s) coarse lanes, then, called again with a per-query
+  window base, the 2^s distances of the coarse bucket that holds r*. Each
+  pair then updates about 2·sqrt(bins) counters instead of bins.
 * **pass 2** (``hamming_emit_pallas``, the "reports"): re-stream the SAME
   tiles, recompute distances in VMEM (recompute is ~free; the scan is
   bandwidth-bound), and scatter the winners straight into their output
@@ -97,6 +101,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.tuning import race_lanes
+
 # rows of the transposed distance tile one scatter step consumes: one
 # sublane group, so every scatter operand is a whole number of vregs
 _GROUP = 8
@@ -155,8 +161,13 @@ def _scatter_groups(sub: int, fn):
 # pass 1: fused distance + histogram (the "race")
 # ---------------------------------------------------------------------------
 
-def _hist_kernel(nv_ref, en_ref, q_ref, x_ref, hist_ref, bmin_ref, dt_ref, *,
-                 bins: int, sub: int, bn: int):
+def _hist_kernel(nv_ref, en_ref, q_ref, x_ref, *refs, bins: int, lanes: int,
+                 shift: int, window: int, sub: int, bn: int):
+    if window:
+        base_ref, hist_ref, dt_ref = refs
+        bmin_ref = None
+    else:
+        hist_ref, bmin_ref, dt_ref = refs
     j = pl.program_id(1)
 
     @pl.when(j == 0)
@@ -165,18 +176,28 @@ def _hist_kernel(nv_ref, en_ref, q_ref, x_ref, hist_ref, bmin_ref, dt_ref, *,
 
     # a disabled tile is outside the candidate set: it contributes nothing
     # to the histogram and summarizes to bins, so pass 2 skips it too
-    bmin_ref[0, 0, j] = jnp.int32(bins)
+    if bmin_ref is not None:
+        bmin_ref[0, 0, j] = jnp.int32(bins)
 
     @pl.when(en_ref[0, 0, j] != 0)
     def _work():
         n_valid = nv_ref[0]
         q = q_ref[...]                                     # (BQ, W)
         bq = q.shape[0]
-        bin_ids = jax.lax.broadcasted_iota(jnp.int32, (bins, _GROUP, bq), 0)
+        bin_ids = jax.lax.broadcasted_iota(jnp.int32, (lanes, _GROUP, bq), 0)
 
         def count(off):
             d8 = dt_ref[pl.ds(off, _GROUP), :]             # (8, BQ)
             hist_ref[0] += (d8[None] == bin_ids).astype(jnp.int32)
+
+        def lane_of(dist):
+            """(sub, BQ) distances -> histogram lanes; invalid rows (at
+            bins) go to lane ``lanes``, outside every histogram bin."""
+            if window:                 # fine level: [base, base + window)
+                return jnp.where(dist < bins, dist - base_ref[0], lanes)
+            if shift:                  # coarse level: dist >> shift
+                return jnp.where(dist < bins, dist >> shift, lanes)
+            return dist
 
         def body(start, xs, bmin):
             dist = _tile_dist(q, xs, bins)                 # (BQ, sub)
@@ -185,13 +206,16 @@ def _hist_kernel(nv_ref, en_ref, q_ref, x_ref, hist_ref, bmin_ref, dt_ref, *,
             # invalid (padding) rows become bins: outside every histogram
             # bin, and a fully-padded tile summarizes to bins > any r*
             dist = jnp.where(gid < n_valid, dist, bins)
-            dt_ref[...] = dist.T                           # (sub, BQ)
+            dt_ref[...] = lane_of(dist.T)                  # (sub, BQ)
             _scatter_groups(sub, count)
-            return jnp.minimum(bmin, dist)
+            return bmin if bmin_ref is None else jnp.minimum(bmin, dist)
 
-        bmin = _sub_tiles(x_ref, bn, sub, body,
-                          jnp.full((bq, sub), bins, jnp.int32))
-        bmin_ref[0, 0, j] = jnp.min(bmin)
+        if bmin_ref is None:
+            _sub_tiles(x_ref, bn, sub, body, jnp.int32(0))
+        else:
+            bmin = _sub_tiles(x_ref, bn, sub, body,
+                              jnp.full((bq, sub), bins, jnp.int32))
+            bmin_ref[0, 0, j] = jnp.min(bmin)
 
 
 def _tile_rows(a: jax.Array, nq: int, nj: int) -> jax.Array:
@@ -212,14 +236,18 @@ def _row_spec(nj: int):
                         memory_space=pltpu.SMEM)
 
 
-@functools.partial(jax.jit, static_argnames=("bins", "bq", "bn", "sub",
-                                             "interpret", "return_tiles"))
+@functools.partial(jax.jit, static_argnames=("bins", "shift", "window", "bq",
+                                             "bn", "sub", "interpret",
+                                             "return_tiles"))
 def hamming_hist_pallas(q_packed: jax.Array, x_packed: jax.Array, bins: int,
                         n_valid: jax.Array | None = None,
                         block_mask: jax.Array | None = None,
+                        base: jax.Array | None = None,
+                        block_min: jax.Array | None = None,
+                        shift: int = 0, window: int = 0,
                         bq: int = 64, bn: int = 1024, sub: int = 64,
                         interpret: bool = False, return_tiles: bool = False):
-    """q: (Q, W), x: (N, W) -> (hist (Q, bins) int32,
+    """q: (Q, W), x: (N, W) -> (hist (Q, lanes) int32,
     block_min (Q/bq, N/bn) int32[, tiles_run int32]).
 
     ``hist`` is the per-query distance histogram; ``block_min`` is the
@@ -231,43 +259,71 @@ def hamming_hist_pallas(q_packed: jax.Array, x_packed: jax.Array, bins: int,
     rows are outside the candidate set, so they are excluded from the
     histogram and its summary entry is bins. ``return_tiles=True`` also
     returns how many grid tiles the kernel ran: the sum of the enable rows
-    it was handed."""
+    it was handed.
+
+    The two levels of the two-level race (``ops._race``) are the same
+    kernel with one static choice each:
+
+    * ``shift > 0`` (coarse): ``hist`` counts ``dist >> shift`` into
+      ``race_lanes(bins, shift)`` lanes; ``block_min`` is as above.
+    * ``window > 0`` with ``base`` (Q,) int32 (fine): ``hist`` (Q, window)
+      counts ``dist - base`` and ignores every distance outside
+      [base, base + window). ``block_min`` (the coarse call's summary; None
+      = no pruning) then also disables every tile whose minimum lies above
+      the widest window of its query block: none of its rows falls in any
+      window, so skipping it is exact. No summary is made (None)."""
     Q, W = q_packed.shape
     N, _ = x_packed.shape
     bq, bn = min(bq, Q), min(bn, N)
     sub = min(sub, bn)
     assert (Q % bq == 0 and N % bn == 0 and bn % sub == 0
             and sub % _GROUP == 0), (Q, N, bq, bn, sub)
+    assert (window > 0) == (base is not None) and not (window and shift)
     nq, nj = Q // bq, N // bn
     nv = jnp.full((1,), N, jnp.int32) if n_valid is None else (
         jnp.asarray(n_valid, jnp.int32).reshape(1))
     en = _tile_rows(jnp.ones((nq, nj), jnp.int32) if block_mask is None
                     else block_mask, nq, nj)
+    lanes = window or race_lanes(bins, shift)
+    in_specs = [
+        pl.BlockSpec(memory_space=pltpu.SMEM),
+        _row_spec(nj),
+        pl.BlockSpec((bq, W), lambda i, j: (i, 0)),
+        pl.BlockSpec((W, bn), lambda i, j: (0, j)),
+    ]
+    hist_spec = pl.BlockSpec((1, lanes, _GROUP, bq), lambda i, j: (i, 0, 0, 0))
+    hist_shape = jax.ShapeDtypeStruct((nq, lanes, _GROUP, bq), jnp.int32)
+    args = [nv, en, *_codes(q_packed, x_packed)]
+    if window:
+        b1 = _as_i32(base).reshape(nq, 1, bq)
+        if block_min is not None:
+            top = jnp.max(b1[:, 0], axis=1, keepdims=True) + (window - 1)
+            en = jnp.where(_tile_rows(block_min, nq, nj) <= top[:, None],
+                           en, 0)
+            args[1] = en
+        in_specs.append(pl.BlockSpec((1, 1, bq), lambda i, j: (i, 0, 0)))
+        args.append(b1)
+        out_specs, out_shape = hist_spec, hist_shape
+    else:
+        out_specs = [hist_spec, _row_spec(nj)]
+        out_shape = [hist_shape,
+                     jax.ShapeDtypeStruct((nq, 1, nj), jnp.int32)]
 
-    hist8, bmin = pl.pallas_call(
-        functools.partial(_hist_kernel, bins=bins, sub=sub, bn=bn),
+    out = pl.pallas_call(
+        functools.partial(_hist_kernel, bins=bins, lanes=lanes, shift=shift,
+                          window=window, sub=sub, bn=bn),
         grid=(nq, nj),
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            _row_spec(nj),
-            pl.BlockSpec((bq, W), lambda i, j: (i, 0)),
-            pl.BlockSpec((W, bn), lambda i, j: (0, j)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, bins, _GROUP, bq), lambda i, j: (i, 0, 0, 0)),
-            _row_spec(nj),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((nq, bins, _GROUP, bq), jnp.int32),
-            jax.ShapeDtypeStruct((nq, 1, nj), jnp.int32),
-        ],
+        in_specs=in_specs,
+        out_specs=out_specs,
+        out_shape=out_shape,
         scratch_shapes=[pltpu.VMEM((sub, bq), jnp.int32)],
         interpret=interpret,
-    )(nv, en, *_codes(q_packed, x_packed))
+    )(*args)
+    hist8, bmin = (out, None) if window else out
+    res = (_unblock(hist8), None if window else bmin.reshape(nq, nj))
     if return_tiles:
-        return (_unblock(hist8), bmin.reshape(nq, nj),
-                jnp.sum(en != 0, dtype=jnp.int32))
-    return _unblock(hist8), bmin.reshape(nq, nj)
+        return res + (jnp.sum(en != 0, dtype=jnp.int32),)
+    return res
 
 
 # ---------------------------------------------------------------------------

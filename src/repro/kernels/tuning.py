@@ -315,6 +315,34 @@ def _topk_blocks_default(Q: int, N: int, W: int, lanes: int,
     return bq, bn, sub
 
 
+# the fused select's pass 1 races in two levels from this many bins up
+# (d + 1). On a v5e (experiments/race_levels.py, N = 2^26, Q = 128; PERF.md)
+# both modes time within 1 % of each other at d = 64, where the second
+# sweep over the codes costs what the histogram lanes save; two levels take
+# 1.39x less time at d = 96, 1.87x at d = 128 and 2.04x at d = 256.
+_TWO_LEVEL_MIN_BINS = 97
+
+
+def race_lanes(bins: int, shift: int) -> int:
+    """Histogram lanes of a pass-1 call that counts ``dist >> shift``
+    over distances [0, bins): ceil(bins / 2^shift)."""
+    return ((bins - 1) >> shift) + 1
+
+
+def race_shift(bins: int) -> int:
+    """The coarse shift s of the fused select's pass 1 (``ops._race``), 0
+    for the one-level race over all ``bins``. A function of the width
+    alone: the coarse level counts ``dist >> s`` into ceil(bins / 2^s)
+    lanes, the fine level a window of 2^s distances (one coarse bucket),
+    and s minimizes the lanes counted per pair, their sum (about log2 of
+    sqrt(bins); on a tie the narrower window, which measured faster at
+    d = 128)."""
+    if bins < _TWO_LEVEL_MIN_BINS:
+        return 0
+    return min(range(1, (bins - 1).bit_length()),
+               key=lambda s: (race_lanes(bins, s) + (1 << s), s))
+
+
 def layout_blocks(Q: int, N: int, W: int, lanes: int, bucket_rows: int,
                   backend: str | None = None) -> tuple[int, int, int]:
     """(bq, bn, sub) for the MASKED select over a bucket-clustered layout
@@ -436,7 +464,8 @@ def shard_hints(Q: int, k: int, bins: int, n_shards: int, *,
 
     ``hist_merge`` (the distributed counting select) moves exactly three
     tiny tensors between devices: the (Q, bins) int32 partial-histogram
-    psum, the (Q, 2)-per-shard slot-base all-gather, and the (Q, k) x2
+    psum (the coarse and fine ones of a two-level race, ``race_shift``),
+    the (Q, 2)-per-shard slot-base all-gather, and the (Q, k) x2
     disjoint-slot output psum — O(Q·bins), independent of n_shards·k.
     ``hist_tree`` moves the SAME tensors but reduces them hierarchically:
     level 0 is the intra-host group psum, the remaining ``tree_levels - 1``
@@ -447,7 +476,11 @@ def shard_hints(Q: int, k: int, bins: int, n_shards: int, *,
     shard's (k' dists, k' ids): O(n_shards·Q·k') candidate bytes. All are
     reported so the ratios are inspectable whatever the plan chose."""
     k_local = k if (k_local is None or k_local <= 0) else k_local
-    hist_psum = 4 * Q * bins
+    shift = race_shift(bins)
+    # pass 1's psums: one (Q, bins) histogram, or a two-level race's
+    # coarse and fine ones
+    hist_psum = 4 * Q * (race_lanes(bins, shift) + (1 << shift)
+                         if shift else bins)
     counts_gather = 2 * 4 * Q * n_shards
     output_psum = 2 * 4 * Q * k
     hist_total = hist_psum + counts_gather + output_psum

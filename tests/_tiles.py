@@ -75,11 +75,16 @@ def probe_enabled(starts: np.ndarray, probe: np.ndarray, bq: int, bn: int,
 
 def tile_counts(q: np.ndarray, x: np.ndarray, k: int, bq: int, bn: int,
                 n_valid: int | None = None, enabled: np.ndarray | None = None,
-                r_star: np.ndarray | None = None) -> dict:
+                r_star: np.ndarray | None = None, shift: int = 0) -> dict:
     """Tiles one two-pass call over codes ``x`` (in the order the kernels
     stream them) skips: {"blocks_total", "p1_blocks_skipped",
-    "blocks_skipped"}. ``r_star`` (Q,) defaults to each query's own radius
-    over its candidate rows; the sharded select passes the global one."""
+    "p1_fine_blocks_skipped", "blocks_skipped"}. ``r_star`` (Q,) defaults
+    to each query's own radius over its candidate rows; the sharded select
+    passes the global one. ``shift``: the race's coarse shift (0: one
+    level, no fine call to skip anything). The fine call runs an enabled
+    tile iff its block minimum (bins = d + 1 where it holds no valid row)
+    lies at or below the top of the widest window of its query block, a
+    window being the 2^shift distances of the coarse bucket of r*."""
     Q, W = q.shape
     N = x.shape[0]
     nv = N if n_valid is None else n_valid
@@ -93,14 +98,21 @@ def tile_counts(q: np.ndarray, x: np.ndarray, k: int, bq: int, bn: int,
     if r_star is None:
         cand = valid[None, :] & row_en[np.arange(Q) // bq]
         r_star = radius(dist[:Q], cand, k)
+    width = 1 << shift
+    top = (np.asarray(r_star) >> shift << shift) + width - 1
     run = np.zeros((nq, nj), bool)
+    fine = np.zeros((nq, nj), bool)
     for i in range(nq):
         widest = r_star[i * bq:(i + 1) * bq].max()
         for j in range(nj):
             rows = np.arange(j * bn, min((j + 1) * bn, N))
             rows = rows[valid[rows]]
-            if en[i, j] and rows.size:
-                run[i, j] = dist[i * bq:(i + 1) * bq, rows].min() <= widest
+            bmin = (dist[i * bq:(i + 1) * bq, rows].min() if rows.size
+                    else W * 32 + 1)
+            if en[i, j]:
+                run[i, j] = rows.size > 0 and bmin <= widest
+                fine[i, j] = bmin <= top[i * bq:(i + 1) * bq].max()
     return {"blocks_total": nq * nj,
             "p1_blocks_skipped": int((~en).sum()),
+            "p1_fine_blocks_skipped": int((~fine).sum()) if shift else 0,
             "blocks_skipped": int((~run).sum())}

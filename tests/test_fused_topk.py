@@ -1,7 +1,7 @@
 """Fused two-pass Pallas top-k (hamming_topk + engine select="fused"):
 equivalence with the oracle and the materialized-distance paths, including
 the padding/masking edges the kernels handle internally; the single-shot
-contract (one hist + one emit pallas_call over the whole datastore, no
+contract (pass 1 and one emit pallas_call over the whole datastore, no
 scan, no merge) and block-min pruning on clustered datastores."""
 import numpy as np
 
@@ -111,7 +111,8 @@ def test_engine_fused_bit_identical(n, q, d, k, chunk):
 
 
 def test_single_shot_one_hist_one_emit(monkeypatch):
-    """select='fused' on N >> chunk must issue exactly one hist and one emit
+    """select='fused' on N >> chunk must issue one pass-1 call per level
+    of the race its width gets (``tuning.race_shift``) and one emit
     pallas_call — no lax.scan over chunks, no merge_topk — and stay
     bit-identical to counting_topk."""
     from repro.kernels import ops as ops_mod
@@ -132,7 +133,8 @@ def test_single_shot_one_hist_one_emit(monkeypatch):
     xb, qb = _data(7, 3000, 4, 64)
     xp, qp = binary.pack_bits(xb), binary.pack_bits(qb)
     fd, fi = engine.search_chunked(xp, qp, 8, 64, chunk=256, select="fused")
-    assert calls == {"hist": 1, "emit": 1}
+    levels = 2 if tuning.race_shift(64 + 1) else 1
+    assert calls == {"hist": levels, "emit": 1}
     cd, ci = topk.counting_topk(binary.hamming_ref(qb, xb), 8, 64)
     assert (fd == cd).all() and (fi == ci).all()
 

@@ -297,7 +297,10 @@ def test_shard_hints_merge_traffic():
     m = p.explain()["geometry"]["merge"]
     assert m["strategy"] == "hist_merge" and m["n_shards"] == s
     bins = d + 1
-    assert m["hist_psum_bytes"] == 4 * q * bins
+    # a two-level race psums its coarse and fine histograms, not all bins
+    shift = tuning.race_shift(bins)
+    assert shift and m["hist_psum_bytes"] == 4 * q * (
+        tuning.race_lanes(bins, shift) + (1 << shift))
     assert m["counts_gather_bytes"] == 2 * 4 * q * s
     assert m["output_psum_bytes"] == 2 * 4 * q * k
     assert m["merge_bytes"] == m["hist_merge_bytes"]

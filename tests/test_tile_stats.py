@@ -24,6 +24,7 @@ BINS = D + 1
 W = D // 32
 STAGES = {"knn.pass1", "knn.radius", "knn.pass2", "knn.finalize"}
 LOCAL_PATHS = ("fused", "fused_scan", "prebuilt", "local_sort", "masked")
+SHIFT = tuning.race_shift(BINS)         # the race this width gets
 
 
 @pytest.fixture(scope="module")
@@ -47,16 +48,18 @@ def _local_case(path, data):
                                                  return_stats=rs)
         if path == "fused":
             bq, bn = ops.topk_geometry(40, N, W, lanes)[:2]
-            return search, _tiles.tile_counts(q, x_sorted, K, bq, bn), STAGES
+            return search, _tiles.tile_counts(q, x_sorted, K, bq, bn,
+                                              shift=SHIFT), STAGES
         # one call per chunk, each over its own rows; counts add up
         bq, bn = ops.topk_geometry(40, CHUNK, W, max(BINS, K))[:2]
         want = {"blocks_total": 0, "p1_blocks_skipped": 0,
-                "blocks_skipped": 0}
+                "p1_fine_blocks_skipped": 0, "blocks_skipped": 0}
         for c in range(-(-N // CHUNK)):
             xc = np.full((CHUNK, W), 0xFFFFFFFF, np.uint32)
             rows = x_sorted[c * CHUNK:(c + 1) * CHUNK]
             xc[:rows.shape[0]] = rows
-            got = _tiles.tile_counts(q, xc, K, bq, bn, n_valid=rows.shape[0])
+            got = _tiles.tile_counts(q, xc, K, bq, bn, n_valid=rows.shape[0],
+                                     shift=SHIFT)
             want = {key: want[key] + got[key] for key in want}
         return search, want, STAGES
 
@@ -67,7 +70,8 @@ def _local_case(path, data):
         eng = engine.KNNEngine(codes=xj, d=D, layout=lay)
         assert eng.query_plan(qj, K).candidates.layout == "prebuilt"
         search = lambda qq, rs: eng.search(qq, K, return_stats=rs)
-        want = _tiles.tile_counts(q, np.asarray(lay.codes), K, bq, bn)
+        want = _tiles.tile_counts(q, np.asarray(lay.codes), K, bq, bn,
+                                  shift=SHIFT)
         return search, want, STAGES | {"knn.layout.map_ids"}
     if path == "local_sort":
         p = plan_mod.plan_local(plan_mod.stats_of(xj, qj, D), K,
@@ -76,7 +80,7 @@ def _local_case(path, data):
         search = lambda qq, rs: plan_mod.execute(p, qq, codes=xj,
                                                  return_stats=rs)
         sorted_codes = np.asarray(layout_mod.local_sort(xj, D)[0])
-        want = _tiles.tile_counts(q, sorted_codes, K, bq, bn)
+        want = _tiles.tile_counts(q, sorted_codes, K, bq, bn, shift=SHIFT)
         return search, want, STAGES | {"knn.layout.map_ids"}
     # masked: two probed buckets per query become the pass-1 enable mask
     probe = np.random.default_rng(2).integers(0, lay.n_buckets, (40, 2))
@@ -90,7 +94,8 @@ def _local_case(path, data):
     bq, bn, _, q_pad, n_pad = ops.topk_geometry(40, N, W, lanes, None, bn)
     en = _tiles.probe_enabled(np.asarray(lay.starts), probe, bq, bn,
                               q_pad // bq, n_pad // bn)
-    want = _tiles.tile_counts(q, np.asarray(lay.codes), K, bq, bn, enabled=en)
+    want = _tiles.tile_counts(q, np.asarray(lay.codes), K, bq, bn, enabled=en,
+                              shift=SHIFT)
     return search, want, STAGES | {"knn.layout.map_ids"}
 
 
@@ -113,6 +118,16 @@ def test_tile_counts_match_brute_force(local):
 
 def test_return_stats_leaves_answers_bit_identical(local):
     (d0, i0), (d1, i1, _) = local["plain"], local["stats"]
+    np.testing.assert_array_equal(np.asarray(d0), np.asarray(d1))
+    np.testing.assert_array_equal(np.asarray(i0), np.asarray(i1))
+
+
+def test_two_level_race_leaves_answers_bit_identical(local, monkeypatch):
+    """Each local fused path answers the same through a two-level race
+    (forced: shift 2 at d = 64, so the clustered queries' radii fall past
+    the first window) as through the one level its width gets."""
+    monkeypatch.setattr(tuning, "race_shift", lambda bins: 2)
+    (d0, i0), (d1, i1) = local["plain"], local["search"](local["q"], False)
     np.testing.assert_array_equal(np.asarray(d0), np.asarray(d1))
     np.testing.assert_array_equal(np.asarray(i0), np.asarray(i1))
 
@@ -148,7 +163,7 @@ import numpy as np, jax, jax.numpy as jnp
 from jax.sharding import Mesh
 import _tiles
 from repro.core import engine, layout as layout_mod
-from repro.kernels import ops
+from repro.kernels import ops, tuning
 
 D, K, N, S = 64, 8, 7936, 4
 merge, fanout, reorder = {merge!r}, {fanout!r}, {reorder!r}
@@ -176,10 +191,11 @@ for s in range(S):
     xs = x[s * n_loc:(s + 1) * n_loc]
     if reorder:
         xs = np.asarray(layout_mod.local_sort(jnp.asarray(xs), D)[0])
-    want.append(_tiles.tile_counts(q, xs, K, bq, bn, r_star=r_glob))
+    want.append(_tiles.tile_counts(q, xs, K, bq, bn, r_star=r_glob,
+                                   shift=tuning.race_shift(D + 1)))
 per = want[0]["blocks_total"]
 assert st["shard_blocks_total"] == per and st["blocks_total"] == S * per
-for key in ("blocks_skipped", "p1_blocks_skipped"):
+for key in ("blocks_skipped", "p1_blocks_skipped", "p1_fine_blocks_skipped"):
     shard = np.asarray(st["shard_" + key])
     assert shard.shape == (S,), shard.shape
     assert shard.tolist() == [w[key] for w in want], (key, shard, want)
@@ -203,4 +219,75 @@ def test_sharded_tile_counts_per_shard(multidevice, merge, fanout, reorder):
     tests = os.path.dirname(os.path.abspath(__file__))
     out = multidevice(SHARDED.format(tests=tests, merge=merge, fanout=fanout,
                                      reorder=reorder), n_devices=4)
+    assert "OK" in out
+
+
+@pytest.mark.parametrize("kind", ["clustered", "uniform"])
+def test_fine_level_skips_tiles_outside_every_window(kind, monkeypatch):
+    """A two-level race (forced: shift 3 at d = 64) counts the tiles its
+    fine call skipped: those whose block minimum lies above every window of
+    their query block. Clustered codes put most tiles there; on uniform
+    codes every tile holds rows near every query's radius, so (almost) none
+    are."""
+    monkeypatch.setattr(tuning, "race_shift", lambda bins: 3)
+    if kind == "clustered":
+        x, q = _tiles.clustered(4, N, D)
+    else:
+        rng = np.random.default_rng(4)
+        x = _tiles.pack(rng.integers(0, 2, (N, D)))
+        q = _tiles.pack(rng.integers(0, 2, (40, D)))
+    _, _, stats = ops.hamming_topk(jnp.asarray(q), jnp.asarray(x), K, BINS,
+                                   return_stats=True)
+    bq, bn = ops.topk_geometry(40, N, W, max(BINS, K))[:2]
+    want = _tiles.tile_counts(q, x, K, bq, bn, shift=3)
+    got = {key: int(stats[key]) for key in want}
+    assert got == want
+    skipped, total = got["p1_fine_blocks_skipped"], got["blocks_total"]
+    if kind == "clustered":
+        assert 0 < skipped < total, got
+    else:
+        assert skipped <= total // 10, got
+
+
+FINE_SHARDED = """
+import sys
+sys.path.insert(0, {tests!r})
+import numpy as np, jax, jax.numpy as jnp
+from jax.sharding import Mesh
+import _tiles
+from repro.core import engine
+from repro.kernels import ops, tuning
+
+D, K, N, S = 64, 8, 4096, 4
+tuning.race_shift = lambda bins: 3
+rng = np.random.default_rng(5)
+data = {{"clustered": _tiles.clustered(5, N, D, rows_per_cluster=512),
+        "uniform": (_tiles.pack(rng.integers(0, 2, (N, D))),
+                    _tiles.pack(rng.integers(0, 2, (40, D))))}}
+mesh = Mesh(np.array(jax.devices()[:S]), ("data",))
+n_loc = N // S
+bq, bn = ops.topk_geometry(40, n_loc, 2, max(D + 1, K))[:2]
+for kind, (x, q) in data.items():
+    with mesh:
+        _, _, st = engine.search_sharded(jnp.asarray(x), jnp.asarray(q), K, D,
+                                         mesh, ("data",), return_stats=True)
+    r_glob = _tiles.radius(_tiles.distances(q, x), np.ones((40, N), bool), K)
+    want = [_tiles.tile_counts(q, x[s * n_loc:(s + 1) * n_loc], K, bq, bn,
+                               r_star=r_glob, shift=3)
+            ["p1_fine_blocks_skipped"] for s in range(S)]
+    shard = np.asarray(st["shard_p1_fine_blocks_skipped"]).tolist()
+    assert shard == want, (kind, shard, want)
+    assert int(st["p1_fine_blocks_skipped"]) == sum(want)
+    per = st["shard_blocks_total"]
+    if kind == "clustered":
+        assert 0 < sum(want) < S * per, (kind, want)
+    else:
+        assert max(want) <= per // 10, (kind, want)
+print("OK")
+"""
+
+
+def test_sharded_fine_level_counts_per_shard(multidevice):
+    tests = os.path.dirname(os.path.abspath(__file__))
+    out = multidevice(FINE_SHARDED.format(tests=tests), n_devices=4)
     assert "OK" in out

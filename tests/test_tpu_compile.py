@@ -1,6 +1,7 @@
 """The main path's kernels compile for a TPU v5e chip that is described, not
 attached: the fused top-k passes at the TPU tiling the heuristic picks for
-the kNN-TagSpace shape (d=256, Q=4096) at N = 2^20 and N = 2^26, and the
+the kNN-TagSpace shape (d=256, Q=4096) at N = 2^20 and N = 2^26, both
+levels of the two-level pass 1 at the benchmark cells' geometry, and the
 materializing distance kernel. Each compiled program must hold the Mosaic
 kernel (``tpu_custom_call``) — what the TPU compiler refuses fails here,
 without a chip. So must the benchmark's two search programs at small N (one
@@ -23,6 +24,7 @@ from repro.kernels.topk_select import hamming_emit_pallas, hamming_hist_pallas
 
 D, Q, K = 256, 4096, 16
 W, BINS = D // 32, D + 1
+SEARCH_Q, SEARCH_ROWS = 128, 1 << 20
 
 
 @pytest.fixture(scope="module", autouse=True)
@@ -74,6 +76,30 @@ def test_hist_compiles_for_v5e(spec, n):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+@pytest.mark.parametrize("level", ["coarse", "fine"])
+def test_two_level_hist_compiles_for_v5e(spec, level):
+    """Both levels of pass 1 at the benchmark cells' geometry: Q = 128,
+    N = 2^26 per chip, d = 256 (bq 128, bn 65536, sub 128)."""
+    n, q = 1 << 26, SEARCH_Q
+    bq, bn, sub = tuning._topk_blocks_default(q, n, W, max(BINS, K), "tpu")
+    assert (bq, bn, sub) == (128, 65536, 128)
+    shift = tuning.race_shift(BINS)
+    tiles = (q // bq, n // bn)
+    if level == "coarse":
+        fn = jax.jit(lambda q, x, nv, m: hamming_hist_pallas(
+            q, x, BINS, nv, block_mask=m, shift=shift, bq=bq, bn=bn,
+            sub=sub))
+        args = (spec(()), spec(tiles))
+    else:
+        fn = jax.jit(lambda q, x, nv, m, b, bm: hamming_hist_pallas(
+            q, x, BINS, nv, block_mask=m, base=b, block_min=bm,
+            window=1 << shift, bq=bq, bn=bn, sub=sub))
+        args = (spec(()), spec(tiles), spec((q,)), spec(tiles))
+    compiled = fn.lower(spec((q, W), jnp.uint32), spec((n, W), jnp.uint32),
+                        *args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
 @pytest.mark.parametrize("n", [1 << 20, 1 << 26])
 def test_emit_compiles_for_v5e(spec, n):
     bq, bn, sub, n_pad, tiles = _geometry(n)
@@ -96,7 +122,6 @@ def test_distance_kernel_compiles_for_v5e(spec):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-SEARCH_Q, SEARCH_ROWS = 128, 1 << 20
 _INSTR = re.compile(r"^\s*(?:ROOT )?%([\w.-]+) = ", re.M)
 
 
@@ -107,12 +132,23 @@ def as_v5e(monkeypatch):
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
 
 
+_KERNEL = re.compile(r'^\s*(?:ROOT )?%([\w.-]+) = .*'
+                     r'custom_call_target="tpu_custom_call"', re.M)
+
+
 def _check_search_program(hlo: str, collectives=()):
     """The benchmark's device-trace readers key on these instruction names;
-    a host callback would keep the program out of the compile cache."""
+    a host callback would keep the program out of the compile cache. Pass 1
+    at d = 256 races in two levels, both calls named
+    ``hamming_hist_pallas``; pass 2 is one ``hamming_emit_pallas``, and no
+    other kernel runs."""
     names = _INSTR.findall(hlo)
     for prefix in ("hamming_hist_pallas", "hamming_emit_pallas") + collectives:
         assert any(n.startswith(prefix) for n in names), prefix
+    kernels = sorted(n.split(".")[0] for n in _KERNEL.findall(hlo))
+    assert tuning.race_shift(BINS) > 0
+    assert kernels == ["hamming_emit_pallas"] + ["hamming_hist_pallas"] * 2, (
+        kernels)
     assert not re.search(r'custom_call_target="[^"]*callback', hlo)
     assert "is_host_transfer=true" not in hlo
 
