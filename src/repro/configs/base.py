@@ -55,10 +55,15 @@ class SSMConfig:
 
 @dataclasses.dataclass(frozen=True)
 class RWKVConfig:
-    head_dim: int = 64
-    # decay LoRA rank for data-dependent decay (Finch)
+    """RWKV-6 (Finch) time-mix widths (arXiv:2404.05892; RWKV-LM ``v6``
+    ``RWKV_Tmix_x060``): every field is a parameter shape of the block."""
+
+    head_dim: int = 64             # WKV head size (state is head_dim^2 a head)
+    # rank of the shared data-dependent token-shift LoRA (``time_maa_w1``,
+    # ``time_maa_w2``: d -> 5 x mix_lora -> 5 x d)
+    mix_lora: int = 32
+    # rank of the data-dependent decay LoRA (``time_decay_w1``, ``_w2``)
     decay_lora: int = 64
-    gate_lora: int = 64
 
 
 @dataclasses.dataclass(frozen=True)
@@ -253,7 +258,7 @@ def scaled_down(cfg: ModelConfig, **overrides) -> ModelConfig:
             cfg.ssm, state_dim=16, head_dim=16, chunk_size=32)
     if cfg.rwkv is not None:
         reduced["rwkv"] = dataclasses.replace(
-            cfg.rwkv, head_dim=32, decay_lora=16, gate_lora=16)
+            cfg.rwkv, head_dim=32, mix_lora=8, decay_lora=16)
     if cfg.retrieval.enabled:
         reduced["retrieval"] = dataclasses.replace(
             cfg.retrieval, code_bits=64, datastore_size=2048, chunk_size=512)

@@ -52,7 +52,12 @@ def itq_project(x: jax.Array, p: ITQParams) -> jax.Array:
     queries at this float precision against the datastore's ±1 bit planes
     (kernels/approx_select.asymmetric_topk) — better ranking fidelity than
     query-side sign quantization at identical datastore bytes."""
-    return (x.astype(jnp.float32) - p.mean) @ p.proj @ p.rot
+    xc = x.astype(jnp.float32) - p.mean
+    # a code bit is the sign of this projection, so it is computed at full
+    # float32 precision (the TPU's default would round operands to bf16)
+    hi = jax.lax.Precision.HIGHEST
+    return jnp.matmul(jnp.matmul(xc, p.proj, precision=hi), p.rot,
+                      precision=hi)
 
 
 def itq_objective(x: jax.Array, p: ITQParams) -> jax.Array:

@@ -198,15 +198,14 @@ def _bucket_probe(q_codes: jax.Array, positions: jax.Array, n_buckets: int,
                                           nprobe, d)
 
 
-def knn_logits(store: DataStore, hidden: jax.Array, rcfg: RetrievalConfig,
-               vocab: int, mesh: Optional[Mesh] = None,
-               axes: Sequence[str] = (), method: str = "xor",
-               temperature: float = 8.0,
-               select: Optional[str] = None,
-               recall_target: Optional[float] = None,
-               nprobe: int = 0,
-               probe_positions: Optional[jax.Array] = None) -> jax.Array:
-    """hidden: (Q, d_model) -> neighbor log-distribution (Q, vocab).
+def knn_search(store: DataStore, hidden: jax.Array, rcfg: RetrievalConfig,
+               mesh: Optional[Mesh] = None, axes: Sequence[str] = (),
+               method: str = "xor", select: Optional[str] = None,
+               recall_target: Optional[float] = None, nprobe: int = 0,
+               probe_positions: Optional[jax.Array] = None):
+    """hidden: (Q, d_model) -> (q_codes (Q, W) uint32, dists (Q, k), ids
+    (Q, k)): the keys' ITQ codes (under the ``knnlm.encode`` scope) and
+    their neighbours in the store.
 
     A thin plan-builder: ``plan_for_store`` resolves the select path,
     layout usage and sharded merge from the store's stats and the config
@@ -222,7 +221,8 @@ def knn_logits(store: DataStore, hidden: jax.Array, rcfg: RetrievalConfig,
     to: only the ``nprobe`` nearest hamming-prefix buckets are scanned.
     ``recall_target`` overrides ``rcfg.recall_target`` for the approx tier
     (the ladder's approx rung serves at a degraded target)."""
-    q_codes = binary.pack_bits(quantize.itq_encode(hidden, store.itq))
+    with jax.named_scope("knnlm.encode"):
+        q_codes = binary.pack_bits(quantize.itq_encode(hidden, store.itq))
     if nprobe > 0 and store.layout is not None and probe_positions is not None:
         p = degraded_plan_for_store(store, rcfg, hidden.shape[0], nprobe)
         probe = _bucket_probe(q_codes, probe_positions,
@@ -239,25 +239,55 @@ def knn_logits(store: DataStore, hidden: jax.Array, rcfg: RetrievalConfig,
         else:
             dists, ids = plan_mod.execute(p, q_codes, codes=store.codes,
                                           layout=store.layout)
-    n = store.values.shape[0]
-    # fewer than k valid neighbors -> the engine pads with sentinels
-    # (full scans: dist = d+1, id >= N; masked probes: id = -1): they must
-    # not receive softmax weight or vote for values[N-1]; mask them out of
-    # the neighbor distribution (an all-invalid row degenerates to p = 0
-    # and hits the log floor below)
-    valid = (ids >= 0) & (ids < n) & (dists <= rcfg.code_bits)   # (Q, k)
-    neighbor_tokens = store.values[jnp.clip(ids, 0, n - 1)]      # (Q, k)
-    w = jax.nn.softmax(
-        jnp.where(valid, -dists.astype(jnp.float32) / temperature, -jnp.inf),
-        axis=-1)
-    w = jnp.where(valid, w, 0.0)
-    p = jnp.zeros((hidden.shape[0], vocab), jnp.float32)
-    p = p.at[jnp.arange(hidden.shape[0])[:, None], neighbor_tokens].add(w)
-    return jnp.log(jnp.maximum(p, 1e-9))
+    return q_codes, dists, ids
+
+
+def knn_distribution(store: DataStore, dists: jax.Array, ids: jax.Array,
+                     rcfg: RetrievalConfig, vocab: int,
+                     temperature: float = 8.0) -> jax.Array:
+    """(dists, ids) (Q, k) -> the neighbour log-distribution (Q, vocab):
+    softmax of -distance / ``temperature`` over the neighbours, summed per
+    stored next token (the vocabulary scatter, scope ``knnlm.logits``)."""
+    with jax.named_scope("knnlm.logits"):
+        n = store.values.shape[0]
+        # fewer than k valid neighbors -> the engine pads with sentinels
+        # (full scans: dist = d+1, id >= N; masked probes: id = -1): they
+        # must not receive softmax weight or vote for values[N-1]; mask
+        # them out of the neighbor distribution (an all-invalid row
+        # degenerates to p = 0 and hits the log floor below)
+        valid = (ids >= 0) & (ids < n) & (dists <= rcfg.code_bits)   # (Q, k)
+        neighbor_tokens = store.values[jnp.clip(ids, 0, n - 1)]      # (Q, k)
+        w = jax.nn.softmax(
+            jnp.where(valid, -dists.astype(jnp.float32) / temperature,
+                      -jnp.inf), axis=-1)
+        w = jnp.where(valid, w, 0.0)
+        q = dists.shape[0]
+        p = jnp.zeros((q, vocab), jnp.float32)
+        p = p.at[jnp.arange(q)[:, None], neighbor_tokens].add(w)
+        return jnp.log(jnp.maximum(p, 1e-9))
+
+
+def knn_logits(store: DataStore, hidden: jax.Array, rcfg: RetrievalConfig,
+               vocab: int, mesh: Optional[Mesh] = None,
+               axes: Sequence[str] = (), method: str = "xor",
+               temperature: float = 8.0,
+               select: Optional[str] = None,
+               recall_target: Optional[float] = None,
+               nprobe: int = 0,
+               probe_positions: Optional[jax.Array] = None) -> jax.Array:
+    """hidden: (Q, d_model) -> neighbor log-distribution (Q, vocab):
+    ``knn_search`` then ``knn_distribution`` (see both)."""
+    _, dists, ids = knn_search(store, hidden, rcfg, mesh=mesh, axes=axes,
+                               method=method, select=select,
+                               recall_target=recall_target, nprobe=nprobe,
+                               probe_positions=probe_positions)
+    return knn_distribution(store, dists, ids, rcfg, vocab, temperature)
 
 
 def interpolate(lm_logits: jax.Array, knn_log_probs: jax.Array,
                 lam: float) -> jax.Array:
-    """log((1-lam) softmax(lm) + lam exp(knn_log_probs))."""
-    lm_logp = jax.nn.log_softmax(lm_logits.astype(jnp.float32), axis=-1)
-    return jnp.logaddexp(lm_logp + jnp.log1p(-lam), knn_log_probs + jnp.log(lam))
+    """log((1-lam) softmax(lm) + lam exp(knn_log_probs)), float32."""
+    with jax.named_scope("knnlm.mix"):
+        lm_logp = jax.nn.log_softmax(lm_logits.astype(jnp.float32), axis=-1)
+        return jnp.logaddexp(lm_logp + jnp.log1p(-lam),
+                             knn_log_probs + jnp.log(lam))

@@ -15,7 +15,7 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.configs.base import ModelConfig, TrainConfig
+from repro.configs.base import BlockKind, ModelConfig, TrainConfig
 from repro.core import retrieval as retrieval_mod
 from repro.dist import sharding
 from repro.models import lm
@@ -144,10 +144,51 @@ def unit_search_steps(bins: int, k: int):
 # serve
 # ---------------------------------------------------------------------------
 
-# (cfg, mesh, max_len, with_retrieval, nprobe, id(probe_positions)) ->
-# (serve_fn, pspecs, sspecs). Degradation rung switches and failover paths
-# re-request builders mid-serve; the cache makes that free.
+# (kind, cfg, mesh, max_len, with_retrieval, nprobe, id(probe_positions),
+# select, recall_target) -> the built step. Degradation rung switches
+# and failover paths re-request steps mid-serve; the cache makes that
+# free.
 _SERVE_CACHE: dict = {}
+
+
+def _memo(kind, cfg, mesh, max_len, with_retrieval, nprobe, probe_positions,
+          select, recall_target, build):
+    try:
+        key = (kind, cfg, mesh, int(max_len), bool(with_retrieval),
+               int(nprobe),
+               id(probe_positions) if probe_positions is not None else None,
+               select,
+               float(recall_target) if recall_target is not None else None)
+        if key in _SERVE_CACHE:
+            return _SERVE_CACHE[key]
+    except TypeError:            # unhashable cfg/mesh: skip memoization
+        key = None
+    out = build()
+    if key is not None:
+        _SERVE_CACHE[key] = out
+    return out
+
+
+def _retrieve_and_mix(cfg, store, hidden, lm_logits, select, recall_target,
+                      nprobe, probe_positions):
+    """The kNN-LM head of a served step: search the keys ``hidden`` (Q, d),
+    build the neighbour distribution and mix it with ``lm_logits`` (Q, V).
+    Returns (mixed log-probs (Q, V) f32, the step's outputs)."""
+    rcfg = cfg.retrieval
+    codes, dists, ids = retrieval_mod.knn_search(
+        store, hidden, rcfg, select=select, recall_target=recall_target,
+        nprobe=nprobe, probe_positions=probe_positions)
+    knn = retrieval_mod.knn_distribution(store, dists, ids, rcfg,
+                                         cfg.vocab_size)
+    mixed = retrieval_mod.interpolate(lm_logits, knn, rcfg.interpolation)
+    return mixed, {"key": hidden, "codes": codes, "dists": dists, "ids": ids,
+                   "lm_logits": lm_logits, "next": _greedy(mixed)}
+
+
+def _greedy(scores):
+    """The greedy token of each row, on the device: the host reads (B,)
+    token ids, not the (B, V) distribution."""
+    return jnp.argmax(scores, axis=-1).astype(jnp.int32)
 
 
 def make_serve_step(cfg: ModelConfig, mesh, max_len: int, *,
@@ -159,52 +200,106 @@ def make_serve_step(cfg: ModelConfig, mesh, max_len: int, *,
     """Returns (serve_fn, param_specs, state_specs).
 
     ``serve_fn(params, token (B,1), state, active (B,)[, store]) ->
-    (logits (B,1,V) f32, new_state)`` — one decode step for every active
-    slot; the store argument exists iff retrieval is on. ``nprobe > 0``
-    (with the store's hamming-prefix ``probe_positions``) builds the
-    DEGRADED serving variant: masked IVF-style probe over the layout at
-    reduced nprobe instead of the full exact plan; ``select="approx"`` +
-    ``recall_target`` builds the APPROX rung — the compute-bound MXU
-    partial-reduce tier at a bounded recall loss. ``global_batch`` is
-    accepted for dry-run symmetry; shapes come from the operands.
+    (log-probs (B,1,V) f32, new_state, outs)`` — one decode step for every
+    active slot; the store argument exists iff retrieval is on. ``outs``
+    holds what the step computed on the way: ``key`` (B, d), the hidden
+    state after the final norm (the retrieval key), ``lm_logits`` (B, V),
+    ``next`` (B,) int32, the argmax of the served distribution (greedy
+    decoding), and with retrieval ``codes`` (B, W) uint32 (the keys' ITQ
+    codes), ``dists`` and ``ids`` (B, k); for RWKV-6 also ``probe``, the
+    last block's recurrence as the step computed it (``lm.decode_step``).
+    Without retrieval the served scores are the LM logits. ``nprobe > 0`` (with the store's
+    hamming-prefix ``probe_positions``) builds the DEGRADED serving
+    variant: masked IVF-style probe over the layout at reduced nprobe
+    instead of the full exact plan; ``select="approx"`` + ``recall_target``
+    builds the APPROX rung — the compute-bound MXU partial-reduce tier at a
+    bounded recall loss. ``global_batch`` is accepted for dry-run symmetry;
+    shapes come from the operands.
     """
     if with_retrieval is None:
         with_retrieval = cfg.retrieval.enabled
-    key = None
-    try:
-        key = (cfg, mesh, int(max_len), bool(with_retrieval), int(nprobe),
-               id(probe_positions) if probe_positions is not None else None,
-               select,
-               float(recall_target) if recall_target is not None else None)
-        if key in _SERVE_CACHE:
-            return _SERVE_CACHE[key]
-    except TypeError:            # unhashable cfg/mesh: skip memoization
-        key = None
-
     ctx = lm.RunCtx(mesh=mesh, dp_axes=dp_axes(mesh))
-    rcfg = cfg.retrieval
 
-    if with_retrieval:
-        def serve_fn_py(params, token, state, active, store):
-            logits, new_state, hidden = lm.decode_step(
-                params, cfg, token, state, ctx, active=active,
-                return_hidden=True)
-            knn = retrieval_mod.knn_logits(
-                store, hidden[:, 0, :], rcfg, cfg.vocab_size,
-                select=select, recall_target=recall_target,
-                nprobe=nprobe, probe_positions=probe_positions)
-            mixed = retrieval_mod.interpolate(logits[:, 0, :], knn,
-                                              rcfg.interpolation)
-            return mixed[:, None, :], new_state
-    else:
-        def serve_fn_py(params, token, state, active):
-            logits, new_state = lm.decode_step(
-                params, cfg, token, state, ctx, active=active)
-            return logits.astype(jnp.float32), new_state
+    probe = cfg.block_pattern[0] == BlockKind.RWKV6
 
-    pspecs = sharding.param_specs(cfg, mesh)
-    sspecs = sharding.decode_state_specs(cfg, mesh)
-    out = (jax.jit(serve_fn_py), pspecs, sspecs)
-    if key is not None:
-        _SERVE_CACHE[key] = out
-    return out
+    def build():
+        def decode(params, token, state, active):
+            with jax.named_scope("knnlm.lm"):
+                logits, new_state, hidden, *seen = lm.decode_step(
+                    params, cfg, token, state, ctx, active=active,
+                    return_hidden=True, return_probe=probe)
+            return (logits[:, 0, :], new_state, hidden[:, 0, :],
+                    {"probe": seen[0]} if probe else {})
+
+        if with_retrieval:
+            def serve_fn_py(params, token, state, active, store):
+                logits, new_state, hidden, extra = decode(params, token,
+                                                          state, active)
+                mixed, outs = _retrieve_and_mix(
+                    cfg, store, hidden, logits, select, recall_target,
+                    nprobe, probe_positions)
+                return mixed[:, None, :], new_state, {**outs, **extra}
+        else:
+            def serve_fn_py(params, token, state, active):
+                logits, new_state, hidden, extra = decode(params, token,
+                                                          state, active)
+                return (logits.astype(jnp.float32)[:, None, :], new_state,
+                        {"key": hidden, "lm_logits": logits,
+                         "next": _greedy(logits), **extra})
+
+        return (jax.jit(serve_fn_py), sharding.param_specs(cfg, mesh),
+                sharding.decode_state_specs(cfg, mesh))
+
+    return _memo("decode", cfg, mesh, max_len, with_retrieval, nprobe,
+                 probe_positions, select, recall_target, build)
+
+
+def make_admit_step(cfg: ModelConfig, mesh, max_len: int, *,
+                    with_retrieval: Optional[bool] = None,
+                    nprobe: int = 0, probe_positions=None,
+                    select: Optional[str] = None,
+                    recall_target: Optional[float] = None):
+    """The slot prefill of serving admission, at the same retrieval variant
+    as ``make_serve_step``'s arguments name.
+
+    ``admit_fn(params, tokens (A,S), lengths (A,), slots (A,), state[,
+    store]) -> (log-probs (A,V) f32, new_state, outs)``: one prefill of the
+    right-padded prompts (``lm.prefill_last``, scope ``knnlm.prefill``)
+    from the recurrent state the slots hold (the server zeroes them first),
+    its final state written into those slots of ``state`` only (slots out
+    of range are padding rows: read as zeros, never written), and the first
+    token's distribution from each prompt's last hidden state with
+    retrieval and interpolation. ``outs`` as ``make_serve_step``'s.
+    """
+    if with_retrieval is None:
+        with_retrieval = cfg.retrieval.enabled
+    ctx = lm.RunCtx(mesh=mesh, dp_axes=dp_axes(mesh))
+
+    def build():
+        def prefill(params, tokens, lengths, slots, state):
+            with jax.named_scope("knnlm.prefill"):
+                hidden, logits, rows = lm.prefill_last(
+                    params, cfg, tokens, lengths,
+                    lm.read_rows(cfg, state, slots), ctx)
+                return hidden, logits, lm.write_rows(cfg, state, rows, slots)
+
+        if with_retrieval:
+            def admit_fn(params, tokens, lengths, slots, state, store):
+                hidden, logits, new_state = prefill(params, tokens, lengths,
+                                                    slots, state)
+                mixed, outs = _retrieve_and_mix(
+                    cfg, store, hidden, logits, select, recall_target,
+                    nprobe, probe_positions)
+                return mixed, new_state, outs
+        else:
+            def admit_fn(params, tokens, lengths, slots, state):
+                hidden, logits, new_state = prefill(params, tokens, lengths,
+                                                    slots, state)
+                return (logits.astype(jnp.float32), new_state,
+                        {"key": hidden, "lm_logits": logits,
+                         "next": _greedy(logits)})
+
+        return jax.jit(admit_fn)
+
+    return _memo("admit", cfg, mesh, max_len, with_retrieval, nprobe,
+                 probe_positions, select, recall_target, build)

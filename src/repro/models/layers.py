@@ -40,6 +40,33 @@ def rmsnorm(params: Params, x: jax.Array, eps: float = 1e-5) -> jax.Array:
 
 
 # ---------------------------------------------------------------------------
+# LayerNorm (with bias)
+# ---------------------------------------------------------------------------
+
+def layernorm_init(dim: int, key=None) -> Params:
+    """Unit scale and zero bias; with ``key``, both drawn about those
+    values (scale 1 + 0.1 N(0, 1), bias 0.1 N(0, 1)), so a seeded model
+    exercises the affine."""
+    if key is None:
+        return {"scale": jnp.ones((dim,), jnp.float32),
+                "bias": jnp.zeros((dim,), jnp.float32)}
+    ks, kb = jax.random.split(key)
+    return {"scale": 1.0 + 0.1 * jax.random.normal(ks, (dim,), jnp.float32),
+            "bias": 0.1 * jax.random.normal(kb, (dim,), jnp.float32)}
+
+
+def layernorm(params: Params, x: jax.Array, eps: float = 1e-5) -> jax.Array:
+    """Normalize over the last axis in float32, then scale and bias; the
+    result keeps ``x``'s dtype."""
+    dt = x.dtype
+    xf = x.astype(jnp.float32)
+    mu = jnp.mean(xf, axis=-1, keepdims=True)
+    var = jnp.mean(jnp.square(xf - mu), axis=-1, keepdims=True)
+    y = (xf - mu) * jax.lax.rsqrt(var + eps) * params["scale"] + params["bias"]
+    return y.astype(dt)
+
+
+# ---------------------------------------------------------------------------
 # Rotary position embeddings
 # ---------------------------------------------------------------------------
 

@@ -5,10 +5,14 @@ block body regardless of depth — essential for 95-layer dry-run compiles).
 Hybrid archs (zamba2) nest the scan: groups of Mamba2 blocks with one
 weight-shared attention block applied per group.
 
-Three entry points:
+Entry points:
   forward        — full-sequence logits (training / scoring)
   prefill        — full sequence + returns the decode state (KV caches / SSM
                    states / RWKV states)
+  prefill_last   — serving admission: right-padded prompts of per-row
+                   lengths from a per-row recurrent state; returns the last
+                   prompt token's hidden state and logits and the rows'
+                   decode state (``write_rows`` puts it into slots)
   decode_step    — one token against the decode state
 """
 from __future__ import annotations
@@ -25,13 +29,13 @@ from repro.models import frontends
 from repro.models.attention import (KVCache, attention_decode, attention_init,
                                     attention_prefill)
 from repro.models.layers import (Params, cross_entropy, dense_init, embed,
-                                 embedding_init, mlp, mlp_init, rmsnorm,
-                                 rmsnorm_init, unembed)
+                                 embedding_init, layernorm, layernorm_init,
+                                 mlp, mlp_init, rmsnorm, rmsnorm_init,
+                                 unembed)
 from repro.models.mamba2 import (MambaState, init_mamba_state, mamba2_forward,
                                  mamba2_init, mamba2_step)
 from repro.models.moe import moe_forward, moe_init
-from repro.models.rwkv6 import (RWKVState, init_rwkv_state, rwkv6_channel_mix,
-                                rwkv6_init, rwkv6_time_mix)
+from repro.models.rwkv6 import init_rwkv_state, rwkv6_block, rwkv6_block_init
 
 
 @dataclasses.dataclass
@@ -91,14 +95,6 @@ def _mamba_block_init(key, cfg: ModelConfig, dtype) -> Params:
     return {"ln": rmsnorm_init(cfg.d_model), "mamba": mamba2_init(key, cfg, dtype)}
 
 
-def _rwkv_block_init(key, cfg: ModelConfig, dtype) -> Params:
-    return {
-        "ln1": rmsnorm_init(cfg.d_model),
-        "tm": rwkv6_init(key, cfg, dtype),
-        "ln2": rmsnorm_init(cfg.d_model),
-    }
-
-
 def init_params(key, cfg: ModelConfig) -> Params:
     dtype = jnp.dtype(cfg.dtype)
     k_emb, k_blocks, k_head, k_shared, k_fe = jax.random.split(key, 5)
@@ -123,11 +119,17 @@ def init_params(key, cfg: ModelConfig) -> Params:
             lambda k: _mamba_block_init(k, cfg, dtype), k_blocks, cfg.num_layers)
     elif kind == BlockKind.RWKV6:
         params["blocks"] = _stacked_init(
-            lambda k: _rwkv_block_init(k, cfg, dtype), k_blocks, cfg.num_layers)
+            lambda k: rwkv6_block_init(k, cfg, dtype), k_blocks, cfg.num_layers)
     else:
         raise ValueError(kind)
 
-    params["final_norm"] = rmsnorm_init(cfg.d_model)
+    if kind == BlockKind.RWKV6:
+        # Finch: LayerNorms with bias, ln0 on the embedding, ln_out last
+        k_ln0, k_out = jax.random.split(jax.random.fold_in(k_head, 1))
+        params["ln0"] = layernorm_init(cfg.d_model, k_ln0)
+        params["final_norm"] = layernorm_init(cfg.d_model, k_out)
+    else:
+        params["final_norm"] = rmsnorm_init(cfg.d_model)
     if not cfg.tie_embeddings:
         params["unembed"] = embedding_init(k_head, cfg.vocab_size, cfg.d_model, dtype)
     if cfg.frontend != "none":
@@ -195,37 +197,45 @@ def _apply_moe_block(p, cfg, ctx: RunCtx, x, positions, want_cache: bool):
     return x, aux, cache
 
 
-def _apply_mamba_block(p, cfg, ctx: RunCtx, x, want_state: bool = False):
+def _apply_mamba_block(p, cfg, ctx: RunCtx, x, want_state: bool = False,
+                       lengths=None, state0=None):
     h = rmsnorm(p["ln"], x, cfg.norm_eps)
     if want_state:
-        y, st = mamba2_forward(p["mamba"], cfg, h, return_state=True)
+        y, st = mamba2_forward(p["mamba"], cfg, h, return_state=True,
+                               lengths=lengths, state0=state0)
         return ctx.shard(x + y, ("batch", "seq", None)), st
     y = mamba2_forward(p["mamba"], cfg, h)
     return ctx.shard(x + y, ("batch", "seq", None))
 
 
-def _apply_rwkv_block(p, cfg, ctx: RunCtx, x, want_state: bool = False):
-    h_in = rmsnorm(p["ln1"], x, cfg.norm_eps)
-    if want_state:
-        tm, s_fin, last_t = rwkv6_time_mix(p["tm"], cfg, h_in, None, return_state=True)
-        h = x + tm
-        c_in = rmsnorm(p["ln2"], h, cfg.norm_eps)
-        cm, last_c = rwkv6_channel_mix(p["tm"], cfg, c_in, None, return_state=True)
-        out = ctx.shard(h + cm, ("batch", "seq", None))
-        return out, RWKVState(wkv=s_fin, shift_t=last_t, shift_c=last_c)
-    h = x + rwkv6_time_mix(p["tm"], cfg, h_in)
-    out = h + rwkv6_channel_mix(p["tm"], cfg, rmsnorm(p["ln2"], h, cfg.norm_eps))
-    return ctx.shard(out, ("batch", "seq", None))
+def _valid(lengths, S: int):
+    """(B,) lengths -> (B, S) mask of each row's own steps (None: all)."""
+    if lengths is None:
+        return None
+    return jnp.arange(S, dtype=jnp.int32)[None, :] < lengths[:, None]
 
 
 # ---------------------------------------------------------------------------
 # forward / prefill
 # ---------------------------------------------------------------------------
 
-def _embed_inputs(params, cfg, tokens, prefix_emb):
+def _embed_tokens(params, cfg, tokens):
     x = embed(params["embed"], tokens)
     if cfg.name.startswith("gemma"):
         x = x * jnp.asarray(cfg.d_model ** 0.5, x.dtype)
+    if "ln0" in params:                 # Finch normalizes the embedding
+        x = layernorm(params["ln0"], x, cfg.norm_eps)
+    return x
+
+
+def _final_norm(params, cfg, x):
+    if "bias" in params["final_norm"]:  # Finch's ln_out
+        return layernorm(params["final_norm"], x, cfg.norm_eps)
+    return rmsnorm(params["final_norm"], x, cfg.norm_eps)
+
+
+def _embed_inputs(params, cfg, tokens, prefix_emb):
+    x = _embed_tokens(params, cfg, tokens)
     if cfg.frontend != "none":
         assert prefix_emb is not None, f"{cfg.name} requires frontend embeddings"
         pre = prefix_emb.astype(x.dtype) @ params["frontend"]["proj"]
@@ -236,27 +246,36 @@ def _embed_inputs(params, cfg, tokens, prefix_emb):
 
 
 def _run_stack(params, cfg: ModelConfig, ctx: RunCtx, x, positions,
-               want_cache: bool = False):
-    """Returns (hidden, aux_loss, caches-or-None)."""
+               want_cache: bool = False, lengths=None, state0=None):
+    """Returns (hidden, aux_loss, caches-or-None). ``lengths`` (B,) marks
+    right padding and ``state0`` (the recurrent part of a decode state's
+    cache, layer-stacked) is the state the rows start from; both only with
+    ``want_cache``, and attention caches always start empty."""
     kind = cfg.block_pattern[0]
     aux0 = jnp.zeros((), jnp.float32)
 
     if cfg.shared_attn_every:
-        def group_body(carry, p_group):
+        def group_body(carry, xs):
             x, aux = carry
+            p_group, m0 = xs if state0 is not None else (xs, None)
 
-            def inner(x, p_layer):
+            def inner(x, xs_l):
+                p_layer, st_l = xs_l if m0 is not None else (xs_l, None)
                 if want_cache:
-                    return _apply_mamba_block(p_layer, cfg, ctx, x, want_state=True)
+                    return _apply_mamba_block(p_layer, cfg, ctx, x, True,
+                                              lengths, st_l)
                 return _apply_mamba_block(p_layer, cfg, ctx, x), None
 
-            x, msts = jax.lax.scan(inner, x, p_group)
+            x, msts = jax.lax.scan(
+                inner, x, p_group if m0 is None else (p_group, m0))
             x2, cache = _apply_attn_mlp(params["shared_attn"], cfg, ctx, x,
                                         positions, want_cache)
             return (x2, aux), (msts, cache)
 
         group_fn = jax.checkpoint(group_body) if ctx.remat else group_body
-        (x, aux), caches = jax.lax.scan(group_fn, (x, aux0), params["blocks"])
+        xs = params["blocks"] if state0 is None else (
+            params["blocks"], state0["mamba"])
+        (x, aux), caches = jax.lax.scan(group_fn, (x, aux0), xs)
         return x, aux, caches
 
     if kind == BlockKind.ATTENTION:
@@ -271,24 +290,31 @@ def _run_stack(params, cfg: ModelConfig, ctx: RunCtx, x, positions,
                                                want_cache)
             return (x, aux + aux_l), cache
     elif kind == BlockKind.MAMBA2:
-        def body(carry, p_layer):
+        def body(carry, xs):
             x, aux = carry
+            p_layer, st_l = xs if state0 is not None else (xs, None)
             if want_cache:
-                x, st = _apply_mamba_block(p_layer, cfg, ctx, x, want_state=True)
+                x, st = _apply_mamba_block(p_layer, cfg, ctx, x, True,
+                                           lengths, st_l)
                 return (x, aux), st
             return (_apply_mamba_block(p_layer, cfg, ctx, x), aux), None
     elif kind == BlockKind.RWKV6:
-        def body(carry, p_layer):
+        valid = _valid(lengths, x.shape[1])
+
+        def body(carry, xs):
             x, aux = carry
-            if want_cache:
-                x, st = _apply_rwkv_block(p_layer, cfg, ctx, x, want_state=True)
-                return (x, aux), st
-            return (_apply_rwkv_block(p_layer, cfg, ctx, x), aux), None
+            p_layer, st_l = xs if state0 is not None else (xs, None)
+            x, st = rwkv6_block(p_layer, cfg, x, st_l, valid)
+            x = ctx.shard(x, ("batch", "seq", None))
+            return (x, aux), (st if want_cache else None)
     else:
         raise ValueError(kind)
 
     body_fn = jax.checkpoint(body) if ctx.remat else body
-    (x, aux), caches = jax.lax.scan(body_fn, (x, aux0), params["blocks"])
+    recurrent = kind in (BlockKind.MAMBA2, BlockKind.RWKV6)
+    xs = (params["blocks"], state0) if (recurrent and state0 is not None) \
+        else params["blocks"]
+    (x, aux), caches = jax.lax.scan(body_fn, (x, aux0), xs)
     return x, aux, caches
 
 
@@ -298,7 +324,7 @@ def forward(params, cfg: ModelConfig, tokens, prefix_emb=None,
     x, positions = _embed_inputs(params, cfg, tokens, prefix_emb)
     x = ctx.shard(x, ("batch", "seq", None))
     x, aux, _ = _run_stack(params, cfg, ctx, x, positions, want_cache=False)
-    h = rmsnorm(params["final_norm"], x, cfg.norm_eps)
+    h = _final_norm(params, cfg, x)
     table = params["embed"] if cfg.tie_embeddings else params["unembed"]
     logits = unembed(table, h)
     logits = ctx.shard(logits, ("batch", "seq", "vocab"))
@@ -331,12 +357,92 @@ def prefill(params, cfg: ModelConfig, tokens, prefix_emb=None,
     x, aux, caches = _run_stack(params, cfg, ctx, x, positions, want_cache=True)
     if cfg.shared_attn_every:
         caches = {"kv": caches[1], "mamba": caches[0]}
-    h = rmsnorm(params["final_norm"], x, cfg.norm_eps)
+    h = _final_norm(params, cfg, x)
     table = params["embed"] if cfg.tie_embeddings else params["unembed"]
     logits = unembed(table, h)
     state = {"pos": jnp.full((tokens.shape[0],), x.shape[1], jnp.int32),
              "cache": caches}
     return logits, state
+
+
+def prefill_last(params, cfg: ModelConfig, tokens, lengths, state=None,
+                 ctx: RunCtx = DEFAULT_CTX):
+    """Serving admission. tokens: (A, S) right-padded prompts, row a holding
+    ``lengths[a]`` >= 1 tokens; ``state``: a decode state of A rows
+    (``read_rows``) whose recurrent part the rows start from (None: zeros;
+    attention caches always start empty, at position 0).
+
+    Returns (hidden (A, d) at each row's last prompt token after the final
+    norm, its logits (A, V), and the rows' decode state with ``pos`` =
+    ``lengths``; the attention cache is S long, for ``write_rows``)."""
+    if cfg.frontend != "none":
+        raise ValueError(f"{cfg.name}: slot prefill takes no frontend inputs")
+    x = _embed_tokens(params, cfg, tokens)
+    A, S = tokens.shape
+    positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[None], (A, S))
+    state0 = None
+    if state is not None and cfg.block_pattern[0] in (BlockKind.MAMBA2,
+                                                      BlockKind.RWKV6):
+        state0 = state["cache"]
+    elif state is not None and cfg.shared_attn_every:
+        state0 = {"mamba": state["cache"]["mamba"]}
+    x, _, caches = _run_stack(params, cfg, ctx, x, positions, want_cache=True,
+                              lengths=lengths, state0=state0)
+    if cfg.shared_attn_every:
+        caches = {"kv": caches[1], "mamba": caches[0]}
+    idx = jnp.maximum(lengths - 1, 0)
+    h = _final_norm(params, cfg, jnp.take_along_axis(
+        x, idx[:, None, None], axis=1)[:, 0])
+    table = params["embed"] if cfg.tie_embeddings else params["unembed"]
+    return h, unembed(table, h), {"pos": lengths.astype(jnp.int32),
+                                  "cache": caches}
+
+
+def _row_axes(cfg: ModelConfig, cache):
+    """The batch axis of every leaf of a decode state's cache: 1 under the
+    layer axis, 2 for the hybrid's (groups, per-group) Mamba states."""
+    if cfg.shared_attn_every:
+        return {"kv": jax.tree_util.tree_map(lambda _: 1, cache["kv"]),
+                "mamba": jax.tree_util.tree_map(lambda _: 2, cache["mamba"])}
+    return jax.tree_util.tree_map(lambda _: 1, cache)
+
+
+def read_rows(cfg: ModelConfig, state, slots):
+    """Rows ``slots`` (A,) of a batch decode state; slots out of range read
+    as zeros."""
+    cache = jax.tree_util.tree_map(
+        lambda a, ax: jnp.take(a, slots, axis=ax, mode="fill", fill_value=0),
+        state["cache"], _row_axes(cfg, state["cache"]))
+    pos = jnp.take(state["pos"], slots, mode="fill", fill_value=0)
+    return {"pos": pos, "cache": cache}
+
+
+def write_rows(cfg: ModelConfig, state, rows, slots):
+    """Write ``rows`` (a decode state of A rows, as ``prefill_last`` returns
+    it) into rows ``slots`` (A,) of the batch decode ``state``. An attention
+    cache fills its first S positions; slots out of range are dropped."""
+    def put(dst, src, ax):
+        idx = (slice(None),) * ax + (slots,) + tuple(
+            slice(0, n) for n in src.shape[ax + 1:])
+        return dst.at[idx].set(src.astype(dst.dtype), mode="drop")
+
+    cache = jax.tree_util.tree_map(put, state["cache"], rows["cache"],
+                                   _row_axes(cfg, state["cache"]))
+    pos = jnp.asarray(state["pos"], jnp.int32).at[slots].set(
+        rows["pos"], mode="drop")
+    return {"pos": pos, "cache": cache}
+
+
+def zero_rows(cfg: ModelConfig, state, slots):
+    """The batch decode ``state`` with rows ``slots`` reset: every recurrent
+    state and cache entry zero, ``pos`` 0."""
+    def zero(dst, ax):
+        return dst.at[(slice(None),) * ax + (slots,)].set(0, mode="drop")
+
+    cache = jax.tree_util.tree_map(zero, state["cache"],
+                                   _row_axes(cfg, state["cache"]))
+    pos = jnp.asarray(state["pos"], jnp.int32).at[slots].set(0, mode="drop")
+    return {"pos": pos, "cache": cache}
 
 
 def _keep_active(active, new, old):
@@ -369,17 +475,21 @@ def _decode_moe_block(p, cfg, ctx, x, cache: KVCache, pos, active):
 
 
 def decode_step(params, cfg: ModelConfig, token, state, ctx: RunCtx = DEFAULT_CTX,
-                active=None, return_hidden: bool = False):
+                active=None, return_hidden: bool = False,
+                return_probe: bool = False):
     """token: (B, 1) int32; state from ``init_decode_state`` or ``prefill``.
     ``pos`` may be per-row; rows with ``active`` False (continuous batching
     free slots) keep their state unchanged.
 
-    Returns (logits (B,1,V), new_state[, hidden])."""
+    Returns (logits (B,1,V), new_state[, hidden][, probe]). ``probe``
+    (RWKV-6 only) is the last block as this step computed it, per row:
+    its input state (``wkv``, ``shift_t``, ``shift_c``) and
+    ``rwkv6_block``'s probe."""
+    if return_probe and cfg.block_pattern[0] != BlockKind.RWKV6:
+        raise ValueError(f"{cfg.name}: only an RWKV-6 stack has a probe")
     pos = jnp.broadcast_to(jnp.asarray(state["pos"], jnp.int32),
                            (token.shape[0],))
-    x = embed(params["embed"], token)
-    if cfg.name.startswith("gemma"):
-        x = x * jnp.asarray(cfg.d_model ** 0.5, x.dtype)
+    x = _embed_tokens(params, cfg, token)
     kind = cfg.block_pattern[0]
 
     if cfg.shared_attn_every:
@@ -416,28 +526,31 @@ def decode_step(params, cfg: ModelConfig, token, state, ctx: RunCtx = DEFAULT_CT
     elif kind == BlockKind.RWKV6:
         def body(x, xs):
             p_layer, st_l = xs
-            h_in = rmsnorm(p_layer["ln1"], x, cfg.norm_eps)
-            tm, s_fin, last_t = rwkv6_time_mix(p_layer["tm"], cfg, h_in, st_l,
-                                               return_state=True)
-            h = x + tm
-            c_in = rmsnorm(p_layer["ln2"], h, cfg.norm_eps)
-            cm, last_c = rwkv6_channel_mix(p_layer["tm"], cfg, c_in, st_l,
-                                           return_state=True)
-            new_st = RWKVState(wkv=s_fin, shift_t=last_t, shift_c=last_c)
-            return h + cm, _keep_active(active, new_st, st_l)
+            x, new_st, *seen = rwkv6_block(p_layer, cfg, x, st_l,
+                                           probe=return_probe)
+            return x, (_keep_active(active, new_st, st_l), *seen)
 
-        x, new_cache = jax.lax.scan(body, x, (params["blocks"], state["cache"]))
+        x, (new_cache, *seen) = jax.lax.scan(
+            body, x, (params["blocks"], state["cache"]))
+        if return_probe:
+            st_in = state["cache"]
+            probe = {n: a[-1][:, 0] for n, a in seen[0].items()}
+            probe.update(wkv=st_in.wkv[-1], shift_t=st_in.shift_t[-1],
+                         shift_c=st_in.shift_c[-1])
     else:
         raise ValueError(kind)
 
-    h = rmsnorm(params["final_norm"], x, cfg.norm_eps)
+    h = _final_norm(params, cfg, x)
     table = params["embed"] if cfg.tie_embeddings else params["unembed"]
     logits = unembed(table, h)
     new_pos = pos + (1 if active is None else active.astype(jnp.int32))
     new_state = {"pos": new_pos, "cache": new_cache}
+    out = (logits, new_state)
     if return_hidden:
-        return logits, new_state, h
-    return logits, new_state
+        out += (h,)
+    if return_probe:
+        out += (probe,)
+    return out
 
 
 def pad_decode_state(cfg: ModelConfig, state, max_len: int):

@@ -65,10 +65,12 @@ def mamba2_init(key, cfg: ModelConfig, dtype) -> Params:
     }
 
 
-def _causal_conv(x: jax.Array, w: jax.Array, state=None):
+def _causal_conv(x: jax.Array, w: jax.Array, state=None, lengths=None):
     """Depthwise causal conv via shifted adds + silu. x: (B, S, C); w: (W, C).
 
-    ``state``: (B, W-1, C) past raw inputs (decode). Returns (y, new_state)."""
+    ``state``: (B, W-1, C) past raw inputs (decode). Returns (y, new_state);
+    with ``lengths`` (B,) the new state holds the W-1 inputs before each
+    row's own length (right padding)."""
     W = w.shape[0]
     if state is None:
         pad = jnp.zeros((x.shape[0], W - 1, x.shape[2]), x.dtype)
@@ -78,11 +80,15 @@ def _causal_conv(x: jax.Array, w: jax.Array, state=None):
     S = x.shape[1]
     y = sum(xp[:, i:i + S, :] * w[i] for i in range(W))
     y = jax.nn.silu(y.astype(jnp.float32)).astype(x.dtype)
+    if lengths is not None:
+        return y, jax.vmap(lambda r, n: jax.lax.dynamic_slice_in_dim(
+            r, n, W - 1, axis=0))(xp, lengths)
     return y, xp[:, -(W - 1):, :]
 
 
-def _ssd_chunked(x, b_mat, c_mat, dt, a_log, chunk: int):
-    """x: (B,S,H,P); b_mat/c_mat: (B,S,N); dt: (B,S,H) f32.
+def _ssd_chunked(x, b_mat, c_mat, dt, a_log, chunk: int, h0=None):
+    """x: (B,S,H,P); b_mat/c_mat: (B,S,N); dt: (B,S,H) f32; ``h0`` the
+    carried (B,H,P,N) state (zeros when None).
 
     Returns (y (B,S,H,P) f32, h_final (B,H,P,N) f32)."""
     B, S, H, P = x.shape
@@ -128,7 +134,7 @@ def _ssd_chunked(x, b_mat, c_mat, dt, a_log, chunk: int):
             "bsh,bsn,bshp->bhpn", rem * dt_l, b_l, x_l)
         return h_new, y
 
-    h0 = jnp.zeros((B, H, P, N), jnp.float32)
+    h0 = jnp.zeros((B, H, P, N), jnp.float32) if h0 is None else h0
     h_final, yc = jax.lax.scan(
         body, h0,
         (xc.swapaxes(0, 1), bc.swapaxes(0, 1), cc.swapaxes(0, 1),
@@ -137,7 +143,8 @@ def _ssd_chunked(x, b_mat, c_mat, dt, a_log, chunk: int):
     return y, h_final
 
 
-def _projections(params: Params, x: jax.Array, state: MambaState | None):
+def _projections(params: Params, x: jax.Array, state: MambaState | None,
+                 lengths=None):
     z = x @ params["w_z"]
     x_in = x @ params["w_x"]
     b_in = x @ params["w_b"]
@@ -146,22 +153,31 @@ def _projections(params: Params, x: jax.Array, state: MambaState | None):
     sx = None if state is None else state.conv_x
     sb = None if state is None else state.conv_b
     sc = None if state is None else state.conv_c
-    x_ssm, nx = _causal_conv(x_in, params["conv_x"], sx)
-    b_mat, nb = _causal_conv(b_in, params["conv_b"], sb)
-    c_mat, nc = _causal_conv(c_in, params["conv_c"], sc)
+    x_ssm, nx = _causal_conv(x_in, params["conv_x"], sx, lengths)
+    b_mat, nb = _causal_conv(b_in, params["conv_b"], sb, lengths)
+    c_mat, nc = _causal_conv(c_in, params["conv_c"], sc, lengths)
     return z, x_ssm, b_mat, c_mat, dt, (nx, nb, nc)
 
 
 def mamba2_forward(params: Params, cfg: ModelConfig, x: jax.Array,
-                   return_state: bool = False):
-    """Full-sequence Mamba2 block. x: (B, S, d_model)."""
+                   return_state: bool = False, lengths=None,
+                   state0: MambaState | None = None):
+    """Full-sequence Mamba2 block. x: (B, S, d_model), from ``state0``
+    (zeros when None). With ``lengths`` (B,) the rows are right-padded:
+    padded steps get dt = 0 (no input, decay 1), so the returned state is
+    each row's state after its own length."""
     ssm = cfg.ssm
     d_inner, n_heads = _dims(cfg)
     B, S, _ = x.shape
-    z, x_ssm, b_mat, c_mat, dt, conv_states = _projections(params, x, None)
+    z, x_ssm, b_mat, c_mat, dt, conv_states = _projections(params, x, state0,
+                                                           lengths)
     dt = jax.nn.softplus(dt.astype(jnp.float32) + params["dt_bias"])
+    if lengths is not None:
+        valid = jnp.arange(S, dtype=jnp.int32)[None, :] < lengths[:, None]
+        dt = jnp.where(valid[..., None], dt, 0.0)
     xh = x_ssm.reshape(B, S, n_heads, ssm.head_dim)
-    y, h = _ssd_chunked(xh, b_mat, c_mat, dt, params["a_log"], ssm.chunk_size)
+    y, h = _ssd_chunked(xh, b_mat, c_mat, dt, params["a_log"], ssm.chunk_size,
+                        None if state0 is None else state0.ssm)
     y = y + params["d_skip"][None, None, :, None] * xh.astype(jnp.float32)
     y = y.reshape(B, S, d_inner).astype(x.dtype)
     y = rmsnorm(params["norm"], y * jax.nn.silu(z), cfg.norm_eps)

@@ -5,13 +5,30 @@ explicit shed policy, per-request deadlines, a graceful plan-degradation
 ladder, and fault-tolerant retrieval with a last-good datastore snapshot.
 
 Requests enter a bounded waiting queue (submissions beyond ``max_queue``
-are SHED immediately — better an explicit reject than unbounded latency);
-free slots admit them by replaying the prompt through the decode step with
-a one-hot ``active`` mask (per-row positions make the shared cache sound);
-each ``tick`` then decodes one token for every live slot. Requests that
-outlive ``deadline_ticks`` are evicted from the queue or their slot with a
-``timed_out`` status instead of occupying capacity forever. Static shapes
-throughout — the TPU-friendly analogue of continuous batching.
+are SHED immediately — better an explicit reject than unbounded latency).
+Free slots admit them by slot prefill: the tick's admissions zero their
+slots' decode state (recurrent state, cache position), run their prompts
+through ONE prefill call (``steps.make_admit_step``: ``max_batch`` rows,
+right-padded to a power-of-two length bucket), write each final state into
+its own slot, and serve each request's first token from its prompt's last
+hidden state, with retrieval and interpolation. No prompt token goes
+through the decode step. Each ``tick`` then decodes one token for every
+live slot. Requests that outlive ``deadline_ticks`` are evicted from the
+queue or their slot with a ``timed_out`` status instead of occupying
+capacity forever. Static shapes throughout — the TPU-friendly analogue of
+continuous batching.
+
+Every served step (admission prefill or decode) keeps what it computed in
+``step_log`` until the next tick: per row the slot and request, and on the
+device the served log-probs and the step's outputs — the retrieval key,
+the query codes, ``(dists, ids)``, the LM logits beside the mixed
+log-probs, and the greedy tokens, the only thing the host reads each step
+(``steps.make_serve_step``). ``counters`` counts ``prefills``,
+``prefill_tokens`` (prompt tokens, padding not counted),
+``slot_state_resets``, ``first_tokens_served``, ``decode_steps`` and
+``retrieval_rows`` (query rows searched, padding rows included). Host
+spans ``server.tick`` and ``server.admit`` name the server's time in a
+profile.
 
 Degradation ladder (``DegradationPolicy``): under pressure (queue depth /
 per-tick latency EWMA) the server downshifts the retrieval QueryPlan one
@@ -229,7 +246,7 @@ class Server:
                 axes=tuple(shard_axes))
         self.rungs = self._build_ladder()
         self.rung = 0
-        self._fns: Dict[Rung, object] = {}
+        self._fns: Dict[tuple, object] = {}
         _, _, self.sspecs = steps_mod.make_serve_step(
             cfg, mesh, max_len, with_retrieval=self.with_retrieval)
         self._rung_fn(self.rungs[0])      # compile path for the top rung
@@ -237,6 +254,9 @@ class Server:
             self.state = jax.jit(
                 lambda: lm.init_decode_state(cfg, max_batch, max_len),
                 out_shardings=sharding.named(mesh, self.sspecs))()
+        self._zero_rows = jax.jit(lambda st, slots: lm.zero_rows(cfg, st,
+                                                                 slots))
+        self.step_log: List[dict] = []
         self.slots: List[Optional[Request]] = [None] * max_batch
         self.last_token = np.zeros((max_batch, 1), np.int32)
         self.waiting: Deque[Request] = collections.deque()
@@ -289,17 +309,23 @@ class Server:
         rungs.append(Rung("retrieval_off", False, 0))
         return rungs
 
-    def _rung_fn(self, r: Rung):
-        if r not in self._fns:
-            fn, _, _ = steps_mod.make_serve_step(
-                self.cfg, self.mesh, self.max_len,
+    def _rung_fn(self, r: Rung, admit: bool = False):
+        """The rung's decode step, or with ``admit`` its slot prefill."""
+        if (r, admit) not in self._fns:
+            kw = dict(
                 with_retrieval=r.retrieval, nprobe=r.nprobe,
                 probe_positions=(self._probe_positions if r.nprobe else None),
                 select=r.select or None,
                 recall_target=(r.recall_target if r.select == "approx"
                                else None))
-            self._fns[r] = fn
-        return self._fns[r]
+            if admit:
+                fn = steps_mod.make_admit_step(self.cfg, self.mesh,
+                                               self.max_len, **kw)
+            else:
+                fn, _, _ = steps_mod.make_serve_step(self.cfg, self.mesh,
+                                                     self.max_len, **kw)
+            self._fns[(r, admit)] = fn
+        return self._fns[(r, admit)]
 
     def _rung_plan_str(self, r: Rung) -> str:
         if not r.retrieval:
@@ -325,25 +351,33 @@ class Server:
         log.info("degradation: %s -> %s (%s); active plan %s",
                  old.name, new.name, why, self._rung_plan_str(new))
 
-    # -- the decode step (guarded) ----------------------------------------
+    # -- the served steps (guarded) ---------------------------------------
 
-    def _step(self, token: np.ndarray, active: np.ndarray, r: Rung):
+    def _run(self, r: Rung, admit: bool, args: tuple):
         if r.nprobe and self.store is not self._full_store:
             # masked-probe fns are compiled against the FULL store's bucket
             # layout; a shard-degraded view has no layout — serve the view
             # through the exact plan instead of a mis-aimed probe
             r = self.rungs[0]
-        fn = self._rung_fn(r)
-        args = (self.params, jnp.asarray(token), self.state,
-                jnp.asarray(active))
+        fn = self._rung_fn(r, admit)
+        args = (self.params,) + args
         if r.retrieval:
             args = args + (self.store,)
+            self.counters["retrieval_rows"] += self.max_batch
         with self.mesh:
-            logits, self.state = fn(*args)
-        return np.asarray(logits.astype(jnp.float32))[:, 0, :]
+            logp, self.state, outs = fn(*args)
+        return logp.reshape(self.max_batch, -1), outs
 
-    def _guarded_step(self, token: np.ndarray, active: np.ndarray):
-        """One decode step at the current rung with the failure ladder:
+    def _step(self, token: np.ndarray, active: np.ndarray, r: Rung):
+        """One decode step at rung ``r``: (served log-probs (B, V), the
+        step's outputs), both on the device; the host reads only
+        ``outs["next"]``, the greedy tokens."""
+        self.counters["decode_steps"] += 1
+        return self._run(r, False, (jnp.asarray(token), self.state,
+                                    jnp.asarray(active)))
+
+    def _guarded(self, step):
+        """``step(rung)`` at the current rung with the failure ladder:
         bounded retry-with-backoff -> last-good snapshot restore ->
         retrieval-off failover. The injector's check sits BEFORE the jitted
         call, so a failed attempt never half-advanced the decode state."""
@@ -353,7 +387,7 @@ class Server:
         def attempt():
             if inj is not None and r.retrieval:
                 inj.check("store_search")
-            return self._step(token, active, r)
+            return step(r)
 
         def count_retry(_e, _attempt):
             self.counters["search_retries"] += 1
@@ -368,7 +402,7 @@ class Server:
             try:
                 if inj is not None:
                     inj.check("store_search")
-                return self._step(token, active, r)
+                return step(r)
             except faults_mod.TRANSIENT:
                 self.counters["search_failures"] += 1
         # shard-loss rung: if the shard layer says part of the fleet is
@@ -379,7 +413,7 @@ class Server:
             try:
                 if inj is not None:
                     inj.check("store_search")
-                out = self._step(token, active, r)
+                out = step(r)
                 self.counters["shard_failover_ticks"] += 1
                 return out
             except faults_mod.TRANSIENT:
@@ -389,7 +423,7 @@ class Server:
         # the store recovers
         self.counters["failover_ticks"] += 1
         self._set_rung(len(self.rungs) - 1, "search failover")
-        return self._step(token, active, self.rungs[self.rung])
+        return step(self.rungs[self.rung])
 
     def _restore_store_snapshot(self) -> bool:
         if self.mstore is not None:
@@ -643,32 +677,56 @@ class Server:
         self.waiting.append(req)
         return True
 
-    def _admit(self, slot: int, req: Request):
-        """Replay the prompt through the decode path for one slot."""
-        req.out_tokens = []
-        req.status, req.admit_tick = ACTIVE, self.ticks
-        if req.queue_ticks is not None:
-            self.queue_wait_ticks.append(req.queue_ticks)
-        self.slots[slot] = req
-        # a reused slot must restart at position 0 — the retiring request
-        # left its row's ``pos`` at wherever it stopped, and the per-row
-        # position is what makes the shared cache sound (stale rows beyond
-        # ``pos`` are masked by position, so no cache wipe is needed)
-        pos = jnp.broadcast_to(jnp.asarray(self.state["pos"], jnp.int32),
-                               (self.max_batch,))
-        self.state = dict(self.state, pos=pos.at[slot].set(0))
-        active = np.zeros(self.max_batch, bool)
-        active[slot] = True
-        tok = np.zeros((self.max_batch, 1), np.int32)
-        # an empty prompt replays a single BOS/zero token: the decode step
-        # still needs one forward to produce first-token logits, and
-        # ``logits`` must never stay None (np.argmax(None) crash)
-        prompt = req.prompt if len(req.prompt) else np.zeros((1,), np.int32)
-        logits = None
-        for t in prompt:
-            tok[slot, 0] = int(t)
-            logits = self._guarded_step(tok, active)
-        self.last_token[slot, 0] = int(np.argmax(logits[slot]))
+    def _reset_slots(self, slots: np.ndarray):
+        """Zero the decode state of ``slots``: recurrent state, cache
+        position (stale cache rows beyond ``pos`` are masked by position)."""
+        self.state = self._zero_rows(self.state, jnp.asarray(slots))
+        self.counters["slot_state_resets"] += int(
+            np.sum(slots < self.max_batch))
+
+    def _admit(self, pairs: List[tuple]):
+        """Admit ``pairs`` of (slot, request) by one slot prefill and serve
+        each request's first token."""
+        n, B = len(pairs), self.max_batch
+        prompts = [req.prompt if len(req.prompt) else np.zeros((1,), np.int32)
+                   for _, req in pairs]     # an empty prompt reads one BOS/0
+        lengths = np.ones((B,), np.int32)
+        lengths[:n] = [len(p) for p in prompts]
+        S = min(self.max_len, 1 << int(lengths.max() - 1).bit_length())
+        tokens = np.zeros((B, S), np.int32)
+        slots = np.full((B,), B, np.int32)      # padding rows: out of range
+        for a, ((slot, req), p) in enumerate(zip(pairs, prompts)):
+            tokens[a, :len(p)] = p
+            slots[a] = slot
+            req.out_tokens = []
+            req.status, req.admit_tick = ACTIVE, self.ticks
+            if req.queue_ticks is not None:
+                self.queue_wait_ticks.append(req.queue_ticks)
+            self.slots[slot] = req
+        self._reset_slots(slots)
+        args = (jnp.asarray(tokens), jnp.asarray(lengths), jnp.asarray(slots))
+
+        def admit(r):
+            return self._run(r, True, args + (self.state,))
+
+        logp, outs = self._guarded(admit)
+        self.counters["prefills"] += 1
+        self.counters["prefill_tokens"] += int(lengths[:n].sum())
+        served = np.zeros((B,), bool)
+        nxt = np.asarray(outs["next"])
+        for a, (slot, req) in enumerate(pairs):
+            tok = int(nxt[a])
+            req.out_tokens.append(tok)
+            self.last_token[slot, 0] = tok
+            served[a] = True
+            self.counters["first_tokens_served"] += 1
+            if len(req.out_tokens) >= req.max_new_tokens:
+                self._retire(slot, DONE, "complete")
+        self.step_log.append({
+            "kind": "admit", "slots": slots,
+            "uids": [req.uid for _, req in pairs] + [-1] * (B - n),
+            "served": served, "logp": logp, "outs": outs})
+        return n
 
     def _expired(self, req: Request) -> bool:
         return (req.deadline_ticks is not None
@@ -702,34 +760,62 @@ class Server:
 
     def tick(self) -> bool:
         """One serving tick. Always advances the clock (deadlines are
-        measured in ticks); returns True iff any decode work happened."""
+        measured in ticks); returns True iff any work (an admission or a
+        decode step) happened."""
+        with jax.profiler.TraceAnnotation("server.tick"):
+            return self._tick()
+
+    def _tick(self) -> bool:
         t0 = time.perf_counter()
+        self.step_log = []
         self._evict_expired()
+        pairs = []
         for i in range(self.max_batch):
-            if self.slots[i] is None and self.waiting:
-                self._admit(i, self.waiting.popleft())
+            while self.slots[i] is None and self.waiting:
+                req = self.waiting.popleft()
+                if len(req.prompt) < self.max_len:
+                    pairs.append((i, req))
+                    break
+                # a prompt that fills the cache leaves no room for a token
+                req.out_tokens, req.admit_tick = [], self.ticks
+                req.status, req.finish_reason = DONE, "capacity"
+                req.finish_tick = self.ticks
+                self.done.append(req)
+                self.counters[DONE] += 1
+        admitted = 0
+        if pairs:
+            with jax.profiler.TraceAnnotation("server.admit"):
+                admitted = self._admit(pairs)
         occupied = np.array([s is not None for s in self.slots])
         if not occupied.any():
             self.ticks += 1
-            self._after_tick(time.perf_counter() - t0, worked=False)
-            return False
+            dt = time.perf_counter() - t0
+            if admitted:
+                self.token_lat_s.extend([dt / admitted] * admitted)
+            self._after_tick(dt, worked=bool(admitted))
+            return bool(admitted)
         # guard capacity: rows at max_len - 1 retire without decoding
         pos = np.asarray(self.state["pos"])
         active = occupied & (pos < self.max_len - 1)
         capped = occupied & ~active
-        logits = self._guarded_step(self.last_token, active) \
-            if active.any() else None
+        logits, outs = self._guarded(
+            lambda r: self._step(self.last_token, active, r)) \
+            if active.any() else (None, None)
         for i in np.where(capped)[0]:
             self._retire(int(i), DONE, "capacity")
-        emitted = 0
+        emitted = admitted
         if logits is not None:
+            nxt = np.asarray(outs["next"])
+            self.step_log.append({
+                "kind": "decode", "slots": np.arange(self.max_batch),
+                "uids": [-1 if r is None else r.uid for r in self.slots],
+                "served": active.copy(), "logp": logits, "outs": outs})
             for i, req in enumerate(self.slots):
                 if req is None or not active[i]:
                     continue
-                nxt = int(np.argmax(logits[i]))
-                req.out_tokens.append(nxt)
+                req.out_tokens.append(int(nxt[i]))
                 emitted += 1
-                self.last_token[i, 0] = nxt
+                self.last_token[i, 0] = nxt[i]
                 if len(req.out_tokens) >= req.max_new_tokens:
                     self._retire(i, DONE, "complete")
         self.ticks += 1

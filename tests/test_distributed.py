@@ -99,7 +99,8 @@ for name in ["gemma-2b", "zamba2-2.7b", "rwkv6-1.6b", "arctic-480b"]:
     store = jax.device_put(store, sharding.named(mesh, sharding.datastore_specs(mesh)))
     token = jnp.zeros((8, 1), jnp.int32)
     active = jnp.ones((8,), bool)
-    logits, state = serve_fn(params, token, state, active, store)
+    logits, state, outs = serve_fn(params, token, state, active, store)
+    assert outs["dists"].shape == (8, cfg.retrieval.k), name
     assert bool(jnp.isfinite(logits).all()), name
 print("OK")
 """)

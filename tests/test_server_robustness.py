@@ -224,9 +224,10 @@ def test_capacity_eviction_retires_and_reuses_slot(env):
     srv.submit(capped), srv.submit(follower)
     _drain(srv, guard=60)
     # the pos < max_len - 1 guard retires the runaway request with exactly
-    # the tokens decoded before the cache filled
+    # the tokens served before the cache filled: the first from the slot
+    # prefill, then one a decode step at positions plen .. max_len - 2
     assert capped.status == "done" and capped.finish_reason == "capacity"
-    assert len(capped.out_tokens) == max_len - 1 - plen
+    assert len(capped.out_tokens) == max_len - plen
     # and its slot was reused: the follower completed in the same slot pool
     assert follower.status == "done" and follower.finish_reason == "complete"
     assert len(follower.out_tokens) == 2
